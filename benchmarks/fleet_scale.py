@@ -37,6 +37,8 @@ from repro.energy import (BatteryConfig, Bernoulli, CompoundPoisson,
                           ControlBounds, DeviceCostModel, FleetConfig,
                           MarkovSolar, ServerController, run_controlled,
                           simulate_fleet)
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_data_mesh
 
 PROCESSES = {
     "bernoulli": lambda n: Bernoulli.create(n, prob=0.35, amount=1.2),
@@ -145,7 +147,7 @@ def bench_round_step(n: int, reps: int = 3) -> dict:
         "unfused_ms": round(unfused_ms, 3),
         "lax_fused_ms": round(lax_ms, 3),
         "pallas_ms": round(pallas_ms, 3),
-        "pallas_interpret": bool(fleet_step.INTERPRET),
+        "pallas_interpret": jax.default_backend() != "tpu",
         "speedup_fused_vs_unfused": round(unfused_ms / lax_ms, 3),
         "modeled_unfused_bytes": int(model["unfused_bytes"]),
         "modeled_fused_bytes": int(model["fused_bytes"]),
@@ -249,6 +251,7 @@ def main():
                     help="replay completed records from --checkpoint-dir and "
                          "only compute the rest")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.resume and not args.checkpoint_dir:
         ap.error("--resume requires --checkpoint-dir")
@@ -327,7 +330,7 @@ def main():
     sharded = []
     n_dev = jax.device_count()
     if n_dev > 1:
-        mesh = jax.make_mesh((n_dev,), ("data",))
+        mesh = make_data_mesh()
         for n in sharded_sizes:
             for policy, process in combos[:2]:
                 with _span("sharded"):

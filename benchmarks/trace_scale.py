@@ -30,6 +30,8 @@ import numpy as np
 from repro.core import Policy
 from repro.energy import (BatteryConfig, DecodeCostModel, FleetConfig,
                           MarkovSolar, TraceHarvest, simulate_fleet)
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_data_mesh
 from repro.serve import (MMPP, BatteryGated, DiurnalPoisson, QoSSpec,
                          ServeConfig, TraceTraffic, simulate_serve)
 from repro.traces import (fit_diurnal_poisson, fit_markov_solar, fit_mmpp,
@@ -170,6 +172,7 @@ def main():
                     help="replay completed records from --checkpoint-dir and "
                          "only compute the rest")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.resume and not args.checkpoint_dir:
         ap.error("--resume requires --checkpoint-dir")
@@ -238,7 +241,7 @@ def main():
     sharded_results = []
     n_dev = jax.device_count()
     if n_dev > 1:
-        mesh = jax.make_mesh((n_dev,), ("data",))
+        mesh = make_data_mesh()
         for n, epochs in sharded:
             with _span("sharded"):
                 rec = cached("sharded", len(sharded_results),

@@ -32,6 +32,7 @@ from repro.core import EnergyProfile, Policy
 from repro.energy import (BatteryConfig, ControlBounds, DeviceCostModel,
                           FleetConfig, MarkovSolar, ServerController,
                           run_controlled, simulate_fleet)
+from repro.launch.mesh import make_data_mesh
 
 ap = argparse.ArgumentParser(description=__doc__)
 ap.add_argument("--checkpoint-dir", default=None,
@@ -70,7 +71,7 @@ cfg = FleetConfig(num_clients=N, policy=Policy.SUSTAINABLE, seed=0,
 
 mesh = None
 if jax.device_count() > 1:
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_data_mesh()
     print(f"sharding the client axis over {jax.device_count()} devices\n")
 
 print(f"fleet: N={N:,}, {ROUNDS} rounds of solar drought "

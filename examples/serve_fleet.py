@@ -42,6 +42,7 @@ from _cli import (add_scenario_flags, assistant_traffic, checkpoint_args,
                   make_obs, scenario_name, solar_harvest)
 from repro.energy import (AdmissionRule, BatteryConfig, ControlBounds,
                           DecodeCostModel, ServerController)
+from repro.launch.mesh import make_data_mesh
 from repro.serve import (BatteryGated, EnergyAgnostic, QoSSpec, ServeConfig,
                          TrainLoad, run_serve_controlled, simulate_serve)
 
@@ -91,7 +92,7 @@ cfg = ServeConfig(num_clients=N, seed=args.seed)
 
 mesh = None
 if jax.device_count() > 1:
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_data_mesh()
     print(f"sharding the client axis over {jax.device_count()} devices\n")
 
 full_j = float(np.asarray(qos.request_cost(cost)))

@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import pallas_call
+
 NEG_INF = -1e30
 
 
@@ -80,7 +82,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                                              "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """q, k, v: (B, S, H, D) with H already GQA-repeated.  Returns (B, S, H, D).
 
     block sizes are clamped to the sequence length (kept MXU-multiples of 128
@@ -102,7 +104,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         _flash_kernel, scale=scale, causal=causal, window=window,
         block_q=block_q, block_k=block_k, num_k_blocks=nk, seq_kv=Skv)
 
-    out = pl.pallas_call(
+    out = pallas_call(
         kernel,
         grid=(B, H, nq, nk),
         in_specs=[

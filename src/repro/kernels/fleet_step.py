@@ -12,7 +12,7 @@ one write of the fleet per round, the roofline lower bound modeled by
 `step_ops.bytes_moved`.
 
 Telemetry fuses too: each grid step reduces its tile's valid-weighted stat
-buffers to one row of a ``(tiles, S)`` partial-sum output; the wrapper sums
+buffers to one row of a ``(tiles, 1, S)`` partial-sum output; the wrapper sums
 rows (and `lax.psum`s across shards) before forming the masked averages, so
 the kernel never materializes a per-client stat buffer in HBM.
 
@@ -26,7 +26,7 @@ the mesh-level edge padding of `energy.fleet._pad_clients` still happens
 outside, before the kernel sees the arrays.
 
 Sharding: `fused_step_sharded` wraps the kernel in a
-``shard_map(check_rep=False)`` over the mesh's data axes — each shard runs
+``jax.shard_map(check_vma=False)`` over the mesh's data axes — each shard runs
 the tile grid over its local client slab (the per-shard slab is re-padded
 to a tile multiple by the same rule) and the stat partials are ``psum``-ed
 before the averages are formed.  RNG-bearing inputs (harvest / requests /
@@ -34,24 +34,20 @@ SUSTAINABLE want) are computed OUTSIDE under GSPMD jit with global client
 indices, so the per-client RNG contract is untouched by the kernel
 boundary.
 
-Interpret mode (CPU CI) follows `kernels.ops`: real lowering on TPU,
-``interpret=True`` elsewhere.
+Interpret mode follows `kernels.platform`: compiled when the program is
+lowered for a TPU, interpreted elsewhere.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.dist import sharding as dist_sharding
 from repro.energy import step_ops
+from repro.kernels.platform import pallas_call
 from repro.obs import hist as hist_lib
-
-# mirrors kernels.ops.INTERPRET (not imported: keep this module's import
-# graph to step_ops + jax so the energy layer can pull it in lazily)
-INTERPRET = jax.default_backend() != "tpu"
 
 DEFAULT_TILE = 65536
 
@@ -99,9 +95,15 @@ def _partials_width(program: step_ops.StepProgram,
     return base
 
 
+def _lanes(width: int) -> int:
+    """Partial-sum row width padded to whole 128-lane vregs."""
+    return -(-width // 128) * 128
+
+
 def _make_kernel(program: step_ops.StepProgram, names: tuple[str, ...],
                  emit: bool, num_groups: int | None):
     n_in = len(names)
+    lanes = _lanes(_partials_width(program, num_groups))
 
     def kernel(*refs):
         env = {nm: refs[i][...] for i, nm in enumerate(names)}
@@ -137,7 +139,13 @@ def _make_kernel(program: step_ops.StepProgram, names: tuple[str, ...],
                                      spec.bins)
             parts += [jnp.sum(valid * (idx == b).astype(jnp.float32))
                       for b in range(spec.bins)]
-        out_refs[k][...] = jnp.stack(parts)[None]
+        # place each scalar partial in its lane by select (Mosaic cannot
+        # concatenate scalars into a vector); unused lanes stay zero
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, lanes), 2)
+        row = jnp.zeros((1, 1, lanes), jnp.float32)
+        for j, part in enumerate(parts):
+            row = jnp.where(lane == j, part, row)
+        out_refs[k][...] = row
 
     return kernel
 
@@ -183,7 +191,6 @@ def fused_step(program: step_ops.StepProgram, env: dict, *, n: int,
     ``lax.psum`` over ``axis_name`` when running per-shard under
     `fused_step_sharded`).
     """
-    interpret = INTERPRET if interpret is None else interpret
     names = _env_names(program, num_groups)
     tile = _tile_for(n, tile)
     n_pad = -(-n // tile) * tile
@@ -214,10 +221,13 @@ def fused_step(program: step_ops.StepProgram, env: dict, *, n: int,
     out_shape = [jax.ShapeDtypeStruct((n_pad,), out_sd[nm].dtype)
                  for nm in out_names]
     width = _partials_width(program, num_groups)
-    out_specs.append(pl.BlockSpec((1, width), lambda i: (i, 0)))
-    out_shape.append(jax.ShapeDtypeStruct((tiles, width), jnp.float32))
+    # (tiles, 1, lanes) with (1, 1, lanes) blocks: the block's last two dims
+    # equal the array's, so every tile count satisfies the TPU tiling rule
+    out_specs.append(pl.BlockSpec((1, 1, _lanes(width)), lambda i: (i, 0, 0)))
+    out_shape.append(jax.ShapeDtypeStruct((tiles, 1, _lanes(width)),
+                                          jnp.float32))
 
-    outs = pl.pallas_call(
+    outs = pallas_call(
         _make_kernel(program, names, emit, num_groups),
         grid=(tiles,),
         in_specs=in_specs,
@@ -226,7 +236,7 @@ def fused_step(program: step_ops.StepProgram, env: dict, *, n: int,
         interpret=interpret,
     )(*inputs)
 
-    partials = jnp.sum(outs[-1], axis=0)                         # (width,)
+    partials = jnp.sum(outs[-1], axis=(0, 1))[:width]            # (width,)
     if axis_name is not None:
         partials = jax.lax.psum(partials, axis_name)
     state = {nm: outs[i][:n] for i, nm in enumerate(program.state_out)}
@@ -267,5 +277,5 @@ def fused_step_sharded(program: step_ops.StepProgram, env: dict, *, n: int,
                           num_groups=num_groups, tile=tile,
                           interpret=interpret, axis_name=daxes)
 
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)(env)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(env)

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import pallas_call
+
 
 def _agg_kernel(s_ref, wstack_ref, w_ref, o_ref):
     s = s_ref[...].astype(jnp.float32)               # (C,)
@@ -30,7 +32,8 @@ def _agg_kernel(s_ref, wstack_ref, w_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def fused_agg(w, w_stack, s, *, block: int = 16384, interpret: bool = True):
+def fused_agg(w, w_stack, s, *, block: int = 16384,
+              interpret: bool | None = None):
     """w (M,), w_stack (C, M), s (C,) -> (M,): w + sum_c s_c (w_c - w)."""
     C, M = w_stack.shape
     block = min(block, M)
@@ -39,7 +42,7 @@ def fused_agg(w, w_stack, s, *, block: int = 16384, interpret: bool = True):
         w = jnp.pad(w, (0, pad))
         w_stack = jnp.pad(w_stack, ((0, 0), (0, pad)))
     Mp = M + pad
-    out = pl.pallas_call(
+    out = pallas_call(
         _agg_kernel,
         grid=(Mp // block,),
         in_specs=[
@@ -54,7 +57,7 @@ def fused_agg(w, w_stack, s, *, block: int = 16384, interpret: bool = True):
     return out[:M]
 
 
-def fused_agg_tree(w_global, w_stack, s, *, interpret: bool = True):
+def fused_agg_tree(w_global, w_stack, s, *, interpret: bool | None = None):
     """Tree-level wrapper: applies ``fused_agg`` leaf-wise (leaves flattened)."""
 
     def leaf(wg, ws):

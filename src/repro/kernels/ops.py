@@ -1,18 +1,14 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-``interpret`` defaults to True in this container (CPU; TPU is the lowering
-TARGET).  On real TPUs set ``repro.kernels.ops.INTERPRET = False`` (or pass
-explicitly) to run the compiled kernels.
+``interpret=None`` (the default) follows `repro.kernels.platform`: compiled
+on a TPU, interpreted elsewhere, decided when the program is lowered.  Pass
+``True``/``False`` to force a mode.
 """
 from __future__ import annotations
-
-import jax
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import fused_agg as _agg
 from repro.kernels import ssd_scan as _ssd
-
-INTERPRET = jax.default_backend() != "tpu"
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
@@ -20,22 +16,18 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     interpret: bool | None = None):
     return _fa.flash_attention(
         q, k, v, causal=causal, window=window, block_q=block_q,
-        block_k=block_k, interpret=INTERPRET if interpret is None else interpret)
+        block_k=block_k, interpret=interpret)
 
 
 def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 128,
              interpret: bool | None = None):
-    return _ssd.ssd_scan(x, dt, A, Bm, Cm, chunk=chunk,
-                         interpret=INTERPRET if interpret is None else interpret)
+    return _ssd.ssd_scan(x, dt, A, Bm, Cm, chunk=chunk, interpret=interpret)
 
 
 def fused_agg(w, w_stack, s, *, block: int = 16384,
               interpret: bool | None = None):
-    return _agg.fused_agg(w, w_stack, s, block=block,
-                          interpret=INTERPRET if interpret is None else interpret)
+    return _agg.fused_agg(w, w_stack, s, block=block, interpret=interpret)
 
 
 def fused_agg_tree(w_global, w_stack, s, *, interpret: bool | None = None):
-    return _agg.fused_agg_tree(
-        w_global, w_stack, s,
-        interpret=INTERPRET if interpret is None else interpret)
+    return _agg.fused_agg_tree(w_global, w_stack, s, interpret=interpret)

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import pallas_call
+
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_ref, *,
                 chunk: int):
@@ -61,7 +63,8 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 128, interpret: bool = True):
+def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 128,
+             interpret: bool | None = None):
     """Chunked SSD scan.  Returns y (B,S,H,P).  S must divide by ``chunk``."""
     B, S, H, P = x.shape
     N = Bm.shape[-1]
@@ -69,7 +72,7 @@ def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 128, interpret: bool = True):
     nC = S // chunk
 
     kernel = functools.partial(_ssd_kernel, chunk=chunk)
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(B, H, nC),
         in_specs=[

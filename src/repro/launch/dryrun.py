@@ -1,12 +1,14 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x input-shape) on the
 production meshes, and extract the roofline terms from the compiled artifact.
 
 MUST be run as its own process (``python -m repro.launch.dryrun``): the
-XLA_FLAGS line above executes before any other import so the 512 placeholder
-CPU devices exist before jax locks the device count.  Nothing is allocated —
+lines above execute before any other import so the 512 placeholder CPU
+devices exist before jax locks the device count, and the run stays on the
+CPU platform even on a machine with an accelerator attached.  Nothing is allocated —
 inputs are ShapeDtypeStructs.
 
 Per combo it records (EXPERIMENTS.md §Dry-run/§Roofline):
@@ -81,15 +83,6 @@ def collective_stats(hlo_text: str) -> dict:
     return out
 
 
-def _cost_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` returns a dict on current jax but a
-    one-element list of dicts on older releases; normalise to a dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def _measure(cfg, shape, mesh, *, local_steps=5, unroll=False):
     """Compile one variant and return np.array([flops, bytes, coll_bytes])
     (per-device)."""
@@ -100,7 +93,7 @@ def _measure(cfg, shape, mesh, *, local_steps=5, unroll=False):
         compiled = jax.jit(bundle.fn, in_shardings=bundle.in_shardings,
                            out_shardings=bundle.out_shardings
                            ).lower(*bundle.args).compile()
-    ca = _cost_dict(compiled)
+    ca = compiled.cost_analysis()
     coll = collective_stats(compiled.as_text())
     return np.array([float(ca.get("flops", 0.0)),
                      float(ca.get("bytes accessed", 0.0)),
@@ -220,7 +213,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
     t1 = time.time()
 
     ma = compiled.memory_analysis()
-    ca = _cost_dict(compiled)
+    ca = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = collective_stats(hlo)
 

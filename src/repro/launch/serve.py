@@ -34,6 +34,7 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke_config
 from repro.energy.costs import DecodeCostModel
+from repro.launch.cache import enable_compile_cache
 from repro.models import get_model
 
 
@@ -117,32 +118,32 @@ def _make_prompt(cfg, rng, batch: int, prompt_len: int) -> dict:
     return prompt
 
 
-def _run_engine(model, params, prompt, args, cache_len, ring, window, rng):
-    """One engine pass over the staggered workload; returns (tokens (B, gen),
-    wall seconds, engine).  Output rows are materialized by construction —
-    the engine fetches each finished slot's row before reclaiming it."""
+def run_engine(model, params, prompts, *, gen: int, slots: int,
+               cache_len: int, stagger: int = 0, ring: bool = False,
+               window=None, greedy: bool = True, temperature: float = 1.0,
+               rng=None):
+    """One `DecodeEngine` pass over ``prompts`` (each a dict of one request's
+    unbatched ``tokens`` (S,) + modality extras; lengths may differ),
+    request ``i`` arriving at step ``i * stagger`` with a budget of ``gen``
+    tokens.  Returns ``({i: Finished}, wall seconds, engine)``.  Output rows
+    are materialized by construction — the engine fetches each finished
+    slot's row before reclaiming it."""
     from repro.serve.engine import DecodeEngine, EngineConfig, Request
 
-    B = args.batch
-    extras_keys = [k for k in prompt if k != "tokens"]
-    reqs = [Request(rid=i, tokens=np.asarray(prompt["tokens"][i]),
-                    max_new=args.gen,
-                    extras={k: np.asarray(prompt[k][i])
-                            for k in extras_keys} or None)
-            for i in range(B)]
-    arrivals = [i * args.stagger for i in range(B)]
+    reqs = [Request(rid=i, tokens=np.asarray(pr["tokens"]), max_new=gen,
+                    extras={k: np.asarray(v) for k, v in pr.items()
+                            if k != "tokens"} or None)
+            for i, pr in enumerate(prompts)]
+    arrivals = [i * stagger for i in range(len(reqs))]
     engine = DecodeEngine(model, params,
-                          EngineConfig(slots=args.slots, cache_len=cache_len,
-                                       max_new=args.gen, ring=ring,
-                                       window=window,
-                                       greedy=not args.sample,
-                                       temperature=args.temperature),
+                          EngineConfig(slots=slots, cache_len=cache_len,
+                                       max_new=gen, ring=ring, window=window,
+                                       greedy=greedy,
+                                       temperature=temperature),
                           rng=rng)
     t0 = time.perf_counter()
     done = engine.run(reqs, arrivals=arrivals)
-    dt = time.perf_counter() - t0
-    toks = np.stack([done[i].tokens for i in range(B)])
-    return toks, dt, engine
+    return done, time.perf_counter() - t0, engine
 
 
 def main():
@@ -168,6 +169,7 @@ def main():
     ap.add_argument("--skip-microbench", action="store_true",
                     help="skip the per-stage microbenchmark (faster smoke)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = get_model(cfg)
@@ -201,11 +203,19 @@ def main():
         path = "single-stream"
         engine = None
     else:
-        toks, wall, engine = _run_engine(model, params, prompt, args,
-                                         cache_len, ring, window, k_sample)
+        prompts = [{k: v[i] for k, v in prompt.items()} for i in range(B)]
+
+        def run():
+            done, dt, engine = run_engine(
+                model, params, prompts, gen=args.gen, slots=args.slots,
+                cache_len=cache_len, stagger=args.stagger, ring=ring,
+                window=window, greedy=not args.sample,
+                temperature=args.temperature, rng=k_sample)
+            return np.stack([done[i].tokens for i in range(B)]), dt, engine
+
+        toks, wall, engine = run()
         # second pass hits the engine's compiled-fns cache -> warm number
-        toks, warm, engine = _run_engine(model, params, prompt, args,
-                                         cache_len, ring, window, k_sample)
+        toks, warm, engine = run()
         path = (f"engine[slots={args.slots} stagger={args.stagger} "
                 f"inserts={engine.stats['inserts']} "
                 f"steps={engine.stats['steps']}]")

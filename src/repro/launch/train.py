@@ -14,9 +14,11 @@ Examples:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import time
 from functools import partial
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +29,7 @@ from repro.configs import get_config, get_smoke_config
 from repro.core import EnergyProfile, FedConfig, parallel_round
 from repro.data import SyntheticImages, SyntheticTokens, iid_partition, \
     FederatedLoader, client_weights
+from repro.launch.cache import enable_compile_cache
 from repro.launch.steps import make_optimizer_for
 from repro.models import get_model
 
@@ -46,6 +49,87 @@ def token_batch_fn(cfg, source, C, T, bc):
                     C, T, bc, cfg.encoder_seq, cfg.d_model), cfg.dtype)
         return batch
     return fn
+
+
+@dataclasses.dataclass
+class TrainRun:
+    """Everything one federated training run needs, built by
+    `setup_training` and driven by `train_rounds`."""
+
+    cfg: Any
+    model: Any
+    fed: FedConfig
+    p: jax.Array                    # (C,) data weights
+    E: jax.Array                    # (C,) energy renewal cycles
+    rng: jax.Array
+    batch_fn: Callable[[int], dict]  # round -> (C, T, ...) batches
+    round_fn: Callable               # jitted `parallel_round`
+
+    def init_params(self):
+        return self.model.init_params(self.rng)
+
+
+def setup_training(cfg, *, clients: int, local_steps: int, batch: int,
+                   seq: int, taus=(1, 2, 4, 8), policy: str = "sustainable",
+                   optimizer: str = "adam", lr: float = 1e-3,
+                   seed: int = 0) -> TrainRun:
+    """Model, schedule, data and the jitted round for one launcher run."""
+    model = get_model(cfg)
+    C, T = clients, local_steps
+    fed = FedConfig(num_clients=C, local_steps=T, policy=policy, seed=seed)
+    opt = make_optimizer_for(cfg, optimizer, lr)
+
+    def loss_fn(params, batch, key):
+        return model.loss_fn(params, batch)
+
+    if cfg.family == "cnn":
+        data = SyntheticImages(num_train=2000, num_test=512, seed=seed)
+        imgs, labels = data.train_set()
+        shards = iid_partition(labels, C, seed)
+        loader = FederatedLoader({"images": imgs, "labels": labels}, shards,
+                                 batch, T, seed)
+        batch_fn = lambda r: jax.tree.map(jnp.asarray, loader.round_batch(r))
+    else:
+        source = SyntheticTokens(cfg.vocab_size, seq, C, seed=seed)
+        batch_fn = token_batch_fn(cfg, source, C, T, batch)
+    return TrainRun(cfg=cfg, model=model, fed=fed, p=jnp.ones((C,)) / C,
+                    E=EnergyProfile(C, tuple(taus)).cycles(),
+                    rng=jax.random.PRNGKey(seed), batch_fn=batch_fn,
+                    round_fn=jax.jit(partial(parallel_round, loss_fn, opt,
+                                             fed)))
+
+
+def train_rounds(run: TrainRun, w, rounds: int, *, start: int = 0,
+                 history: list | None = None, obs=None, after_round=None):
+    """The launcher's round loop: rounds ``start .. rounds-1`` from global
+    model ``w``.  Each round's batches and key derive from its absolute
+    index, so a resumed run replays bit-exactly.  ``after_round(r, w,
+    history)`` runs after each round (checkpointing).  Returns ``(w,
+    history)``; history records hold host floats, so every round is
+    materialized before the next is dispatched."""
+    history = [] if history is None else history
+    t0 = time.time()
+    for r in range(start, rounds):
+        args = (run.batch_fn(r), run.p, run.E, jnp.int32(r),
+                jax.random.fold_in(run.rng, r))
+        if obs is not None:
+            with obs.span("train_round"):
+                w, m = run.round_fn(w, *args)
+                m = jax.tree.map(np.asarray, m)
+        else:
+            w, m = run.round_fn(w, *args)
+        rec = {"round": r, "loss": float(m["loss"]),
+               "participants": float(m["participants"])}
+        history.append(rec)
+        if obs is not None:
+            obs.event("round", scan="train", **rec)
+        if after_round is not None:
+            after_round(r, w, history)
+        if r % max(1, rounds // 10) == 0 or r == rounds - 1:
+            print(f"round {r:4d} loss={rec['loss']:.4f} "
+                  f"participants={rec['participants']:.0f} "
+                  f"({time.time()-t0:.1f}s)", flush=True)
+    return w, history
 
 
 def main():
@@ -80,36 +164,20 @@ def main():
                     help="stream a repro.obs run (manifest + per-round "
                          "events + span timings) to this directory")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    model = get_model(cfg)
     C, T = args.clients, args.local_steps
     taus = tuple(int(x) for x in args.taus.split(","))
-    E = EnergyProfile(C, taus).cycles()
-    p = jnp.ones((C,)) / C
-    fed = FedConfig(num_clients=C, local_steps=T, policy=args.policy,
-                    seed=args.seed)
-    opt = make_optimizer_for(cfg, args.optimizer, args.lr)
-
-    rng = jax.random.PRNGKey(args.seed)
-    w = model.init_params(rng)
-    n_params = model.num_params(w)
+    run = setup_training(cfg, clients=C, local_steps=T, batch=args.batch,
+                         seq=args.seq, taus=taus, policy=args.policy,
+                         optimizer=args.optimizer, lr=args.lr, seed=args.seed)
+    fed = run.fed
+    w = run.init_params()
+    n_params = run.model.num_params(w)
     print(f"arch={cfg.name} family={cfg.family} params={n_params:,} "
-          f"clients={C} T={T} policy={args.policy} E={list(np.asarray(E))}")
-
-    def loss_fn(params, batch, key):
-        return model.loss_fn(params, batch)
-
-    if cfg.family == "cnn":
-        data = SyntheticImages(num_train=2000, num_test=512, seed=args.seed)
-        imgs, labels = data.train_set()
-        shards = iid_partition(labels, C, args.seed)
-        loader = FederatedLoader({"images": imgs, "labels": labels}, shards,
-                                 args.batch, T, args.seed)
-        batch_fn = lambda r: jax.tree.map(jnp.asarray, loader.round_batch(r))
-    else:
-        source = SyntheticTokens(cfg.vocab_size, args.seq, C, seed=args.seed)
-        batch_fn = token_batch_fn(cfg, source, C, T, args.batch)
+          f"clients={C} T={T} policy={args.policy} "
+          f"E={list(np.asarray(run.E))}")
 
     ckptr, cfg_hash, start, history = None, None, 0, []
     if args.resume and not args.checkpoint_dir:
@@ -151,38 +219,20 @@ def main():
                                local_steps=T, optimizer=args.optimizer,
                                lr=args.lr)
 
-    def save_run(round_done):
+    def save_run(r, w, history):
+        if ckptr is None or not ((r + 1) % max(1, args.checkpoint_every) == 0
+                                 or r == args.rounds - 1):
+            return
         from repro.checkpoint import resume as resume_lib
         resume_lib.save_run(
-            ckptr, kind="train", round_offset=round_done, state=w,
+            ckptr, kind="train", round_offset=r + 1, state=w,
             stats={"loss": np.asarray([h["loss"] for h in history]),
                    "participants": np.asarray(
                        [h["participants"] for h in history])},
             config_hash=cfg_hash, seed=args.seed)
 
-    round_fn = jax.jit(partial(parallel_round, loss_fn, opt, fed))
-    t0 = time.time()
-    for r in range(start, args.rounds):
-        if obs is not None:
-            with obs.span("train_round"):
-                w, m = round_fn(w, batch_fn(r), p, E, jnp.int32(r),
-                                jax.random.fold_in(rng, r))
-                m = jax.tree.map(np.asarray, m)
-        else:
-            w, m = round_fn(w, batch_fn(r), p, E, jnp.int32(r),
-                            jax.random.fold_in(rng, r))
-        rec = {"round": r, "loss": float(m["loss"]),
-               "participants": float(m["participants"])}
-        history.append(rec)
-        if obs is not None:
-            obs.event("round", scan="train", **rec)
-        if ckptr is not None and ((r + 1) % max(1, args.checkpoint_every) == 0
-                                  or r == args.rounds - 1):
-            save_run(r + 1)
-        if r % max(1, args.rounds // 10) == 0 or r == args.rounds - 1:
-            print(f"round {r:4d} loss={rec['loss']:.4f} "
-                  f"participants={rec['participants']:.0f} "
-                  f"({time.time()-t0:.1f}s)", flush=True)
+    w, history = train_rounds(run, w, args.rounds, start=start,
+                              history=history, obs=obs, after_round=save_run)
     if args.ckpt:
         save_checkpoint(args.ckpt, w, step=args.rounds,
                         metadata={"arch": cfg.name, "policy": args.policy})

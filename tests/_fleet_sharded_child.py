@@ -16,6 +16,7 @@ from repro.core import EnergyProfile, Policy
 from repro.energy import (BatteryConfig, Bernoulli, FleetConfig, MarkovSolar,
                           TraceHarvest, simulate_fleet)
 from repro.energy.fleet import FLEET_POLICIES, _run_fleet_scan
+from repro.launch.mesh import make_data_mesh, make_mesh
 
 
 def check_parity(mesh, n, rounds=30):
@@ -241,7 +242,7 @@ def check_obs_noop(mesh, n, big_n=1_000_000):
 def main():
     n_dev = len(jax.devices())
     assert n_dev == 8, f"expected 8 emulated CPU devices, got {n_dev}"
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_data_mesh(8)
     check_parity(mesh, n=24)    # divisible by the 8-way client axis
     check_parity(mesh, n=21)    # padded 21 -> 24 (phantom-lane path)
     check_stochastic(mesh, n=24)
@@ -255,7 +256,7 @@ def main():
     check_sharded_cache_reuse(mesh, n=32)
     check_obs_noop(mesh, n=24)
     # a mesh with a model axis: fleet state shards over data axes only
-    mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh2 = make_mesh((4, 2), ("data", "model"))
     check_parity(mesh2, n=21)   # padded 21 -> 24 (4-way data axis)
     check_kernel_parity(mesh2, n=21)
     print("sharded parity OK")

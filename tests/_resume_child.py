@@ -57,9 +57,11 @@ def make_mesh(want_mesh):
         return None
     import jax
 
+    from repro.launch.mesh import make_data_mesh
+
     n_dev = len(jax.devices())
     assert n_dev == 8, f"expected 8 emulated CPU devices, got {n_dev}"
-    return jax.make_mesh((8,), ("data",))
+    return make_data_mesh(8)
 
 
 def run_fleet(args, mesh, ckpt):
