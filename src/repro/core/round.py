@@ -155,18 +155,24 @@ def parallel_round(
     leading C axis; ``constrain`` (dist.sharding.stacked_constrainer) pins it
     to the mesh's data axes so the local phase is communication-free and the
     final aggregation lowers to one reduction over the client axis.
+
+    Named scopes ``schedule``, ``broadcast``, ``local_step``, ``optimizer``
+    and ``aggregate`` tag the round's ops for a device trace (DESIGN.md
+    §12); they change op metadata only.
     """
     n = cfg.num_clients
     cst = constrain if constrain is not None else (lambda t: t)
     cst_opt = constrain_opt if constrain_opt is not None else cst
-    mask = scheduling.participation_mask(cfg.policy, cfg.seed, rnd, E,
-                                         phase=cfg.phase_array())
-    scale = scheduling.aggregation_scale(cfg.policy, E)
+    with jax.named_scope("schedule"):
+        mask = scheduling.participation_mask(cfg.policy, cfg.seed, rnd, E,
+                                             phase=cfg.phase_array())
+        scale = scheduling.aggregation_scale(cfg.policy, E)
 
     # stacked local models, fresh per-round local optimizer state (eq. 6)
-    w_stack = cst(jax.tree.map(
-        lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), w_global))
-    opt_state = cst_opt(optimizer.init(w_stack))
+    with jax.named_scope("broadcast"):
+        w_stack = cst(jax.tree.map(
+            lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), w_global))
+        opt_state = cst_opt(optimizer.init(w_stack))
     keys = jax.vmap(lambda i: jax.random.fold_in(rng, i))(jnp.arange(n))
 
     # (C, T, ...) -> (T, C, ...) for the local-step scan (eq. 7)
@@ -178,8 +184,10 @@ def parallel_round(
         w, s = carry
         batch, t = inp
         kt = jax.vmap(lambda k: jax.random.fold_in(k, t))(keys)
-        losses, grads = jax.vmap(vg)(w, batch, kt)
-        w, s = optimizer.update(grads, s, w, t)
+        with jax.named_scope("local_step"):
+            losses, grads = jax.vmap(vg)(w, batch, kt)
+        with jax.named_scope("optimizer"):
+            w, s = optimizer.update(grads, s, w, t)
         return (cst(w), cst_opt(s)), losses
 
     # global schedule index: Theorem 1's eta_t keeps decaying across rounds
@@ -189,10 +197,14 @@ def parallel_round(
                                         unroll=bool(cfg.unroll))
     losses = jnp.mean(losses, axis=0)  # (C,) mean local loss per client
 
-    w_new = aggregation.aggregate(w_global, w_stack, mask, p, scale, cfg.server_lr)
+    with jax.named_scope("aggregate"):
+        w_new = aggregation.aggregate(w_global, w_stack, mask, p, scale,
+                                      cfg.server_lr)
     metrics = {
         "loss": jnp.sum(losses * mask) / jnp.maximum(jnp.sum(mask), 1.0),
         "participants": jnp.sum(mask),
+        # client-local steps computed: every client runs all T today
+        "client_steps": jnp.int32(n * cfg.local_steps),
     }
     return w_new, metrics
 

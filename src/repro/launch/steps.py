@@ -138,7 +138,8 @@ def build_train_step(cfg: ModelConfig, shape: InputShape, mesh,
             b_sh,
             _repl(mesh), _repl(mesh), _repl(mesh), _repl(mesh),
         )
-        out_sh = (p_sh, {"loss": _repl(mesh), "participants": _repl(mesh)})
+        out_sh = (p_sh, {k: _repl(mesh)
+                         for k in ("loss", "participants", "client_steps")})
         zero = "model" if (dp_mode and cfg.zero_opt_over_model) else None
         fn = partial(parallel_round, loss_fn, opt, fed,
                      constrain=shard.stacked_constrainer(

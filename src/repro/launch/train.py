@@ -32,6 +32,8 @@ from repro.data import SyntheticImages, SyntheticTokens, iid_partition, \
 from repro.launch.cache import enable_compile_cache
 from repro.launch.steps import make_optimizer_for
 from repro.models import get_model
+from repro.obs.metrics import Counter
+from repro.obs.profile import gc_spans, span
 
 
 def token_batch_fn(cfg, source, C, T, bc):
@@ -51,6 +53,13 @@ def token_batch_fn(cfg, source, C, T, bc):
     return fn
 
 
+# what `train_rounds` counts; ``train.client_steps_useful`` are the
+# participants' local steps (participants x T)
+TRAIN_COUNTERS = ("train.rounds", "train.client_steps_computed",
+                  "train.client_steps_useful", "train.gc_collections",
+                  "train.gc_ms")
+
+
 @dataclasses.dataclass
 class TrainRun:
     """Everything one federated training run needs, built by
@@ -64,6 +73,8 @@ class TrainRun:
     rng: jax.Array
     batch_fn: Callable[[int], dict]  # round -> (C, T, ...) batches
     round_fn: Callable               # jitted `parallel_round`
+    counters: dict[str, Counter] = dataclasses.field(
+        default_factory=lambda: {n: Counter(n) for n in TRAIN_COUNTERS})
 
     def init_params(self):
         return self.model.init_params(self.rng)
@@ -106,29 +117,54 @@ def train_rounds(run: TrainRun, w, rounds: int, *, start: int = 0,
     index, so a resumed run replays bit-exactly.  ``after_round(r, w,
     history)`` runs after each round (checkpointing).  Returns ``(w,
     history)``; history records hold host floats, so every round is
-    materialized before the next is dispatched."""
+    materialized before the next is dispatched.
+
+    Each round is a ``train.round`` span (`repro.obs.profile.span`, with
+    its index) tiled by ``train.batch``, ``train.args``, ``train.dispatch``,
+    ``train.fetch`` and ``train.after_round``; each garbage collection is
+    a ``train.gc`` span; ``run.counters`` count the rounds, the client
+    steps computed and those of participants, and the collections.  With
+    ``obs`` the spans are events too and the counters ride on its closing
+    ``metrics`` event."""
     history = [] if history is None else history
+    count, T = run.counters, run.fed.local_steps
+    if obs is not None:
+        for c in count.values():
+            obs.metrics.attach(c)
+
+    def collected(ms):
+        count["train.gc_collections"].inc()
+        count["train.gc_ms"].inc(ms)
+
     t0 = time.time()
-    for r in range(start, rounds):
-        args = (run.batch_fn(r), run.p, run.E, jnp.int32(r),
-                jax.random.fold_in(run.rng, r))
-        if obs is not None:
-            with obs.span("train_round"):
-                w, m = run.round_fn(w, *args)
-                m = jax.tree.map(np.asarray, m)
-        else:
-            w, m = run.round_fn(w, *args)
-        rec = {"round": r, "loss": float(m["loss"]),
-               "participants": float(m["participants"])}
-        history.append(rec)
-        if obs is not None:
-            obs.event("round", scan="train", **rec)
-        if after_round is not None:
-            after_round(r, w, history)
-        if r % max(1, rounds // 10) == 0 or r == rounds - 1:
-            print(f"round {r:4d} loss={rec['loss']:.4f} "
-                  f"participants={rec['participants']:.0f} "
-                  f"({time.time()-t0:.1f}s)", flush=True)
+    with gc_spans("train.gc", collected):
+        for r in range(start, rounds):
+            with span("train.round", obs, round=r):
+                with span("train.batch", obs):
+                    batches = run.batch_fn(r)
+                with span("train.args", obs):
+                    rnd, key = jnp.int32(r), jax.random.fold_in(run.rng, r)
+                with span("train.dispatch", obs):
+                    w, m = run.round_fn(w, batches, run.p, run.E, rnd, key)
+                with span("train.fetch", obs):
+                    m = jax.device_get(m)
+                    rec = {"round": r, "loss": float(m["loss"]),
+                           "participants": float(m["participants"])}
+                    history.append(rec)
+                    count["train.rounds"].inc()
+                    count["train.client_steps_computed"].inc(
+                        int(m["client_steps"]))
+                    count["train.client_steps_useful"].inc(
+                        int(rec["participants"]) * T)
+                    if obs is not None:
+                        obs.event("round", scan="train", **rec)
+                with span("train.after_round", obs):
+                    if after_round is not None:
+                        after_round(r, w, history)
+                    if r % max(1, rounds // 10) == 0 or r == rounds - 1:
+                        print(f"round {r:4d} loss={rec['loss']:.4f} "
+                              f"participants={rec['participants']:.0f} "
+                              f"({time.time()-t0:.1f}s)", flush=True)
     return w, history
 
 

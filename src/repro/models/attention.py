@@ -138,6 +138,7 @@ def blocked_attention(q, k, v, *, causal: bool, window: int = 0,
     return jnp.einsum("bhqd->bqhd", out).astype(q.dtype)
 
 
+@jax.named_scope("attention")
 def attention(cfg: ModelConfig, p, x, *, positions=None, causal=True,
               window=None, memory=None, impl: str = "ref"):
     """Full attention over a sequence (training / encoder / cross-attention).

@@ -68,6 +68,7 @@ def mlp_init(cfg: ModelConfig, rng, shape_prefix=(), d_ff=None):
     }
 
 
+@jax.named_scope("mlp")
 def apply_mlp(cfg: ModelConfig, p, x):
     if cfg.mlp_type == "swiglu":
         h = x @ p["wi"]
@@ -105,6 +106,7 @@ def embed_init(cfg: ModelConfig, rng):
     return p
 
 
+@jax.named_scope("embed")
 def embed_tokens(cfg: ModelConfig, p, tokens, pos_offset=0):
     x = jnp.take(p["tok"], tokens, axis=0)
     if cfg.pos_type == "learned":
@@ -117,6 +119,7 @@ def embed_tokens(cfg: ModelConfig, p, tokens, pos_offset=0):
     return x
 
 
+@jax.named_scope("head")
 def unembed(cfg: ModelConfig, p, x, *, padded: bool = False):
     """Project to vocab logits (fp32).
 
@@ -166,6 +169,7 @@ def apply_rope(x, positions, theta: float):
 
 
 # --------------------------------------------------------------- losses ----
+@jax.named_scope("head")
 def softmax_xent(logits, labels, mask=None, valid_vocab: int | None = None):
     """Mean token cross-entropy; logits fp32 (B, S, Vp), labels int (B, S).
 

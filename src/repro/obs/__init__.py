@@ -44,11 +44,14 @@ from repro.obs.metrics import (
     Gauge,
     MetricStream,
     Obs,
+    counter_totals,
+    reset_counters,
 )
 from repro.obs.profile import (
     RetraceSentinel,
-    annotate,
-    profiler_trace,
+    SpanRecord,
+    gc_spans,
+    recent_spans,
     reset_spans,
     span,
     span_totals,
@@ -61,8 +64,8 @@ __all__ = [
     "FLEET_HIST_SPECS", "SERVE_HIST_SPECS", "HistSpec", "masked_bincount",
     "quantiles_from_counts", "sparkline",
     "ENERGY_SEVEN", "GROUP_KEYS", "SERVE_LEDGER", "Counter", "Gauge",
-    "MetricStream", "Obs",
-    "RetraceSentinel", "annotate", "profiler_trace", "reset_spans", "span",
-    "span_totals",
+    "MetricStream", "Obs", "counter_totals", "reset_counters",
+    "RetraceSentinel", "SpanRecord", "gc_spans", "recent_spans",
+    "reset_spans", "span", "span_totals",
     "bench_diff", "dist", "render_dist", "render_summary", "summarize",
 ]

@@ -59,6 +59,20 @@ def _scalarize(v):
     return a.tolist()
 
 
+# name -> the sum of every Counter of that name; survives its counters
+_COUNTER_TOTALS: dict[str, float] = {}
+
+
+def counter_totals() -> dict[str, float]:
+    """What every counter of each name counted since the last
+    `reset_counters` (the counterpart of `profile.span_totals`)."""
+    return dict(_COUNTER_TOTALS)
+
+
+def reset_counters() -> None:
+    _COUNTER_TOTALS.clear()
+
+
 class Counter:
     """Monotone event counter (rounds seen, chunks, retraces...)."""
 
@@ -68,6 +82,7 @@ class Counter:
 
     def inc(self, by: int = 1) -> int:
         self.value += by
+        _COUNTER_TOTALS[self.name] = _COUNTER_TOTALS.get(self.name, 0) + by
         return self.value
 
 
@@ -94,6 +109,10 @@ class MetricStream:
 
     def counter(self, name: str) -> Counter:
         return self._counters.setdefault(name, Counter(name))
+
+    def attach(self, counter: Counter) -> None:
+        """Snapshot a counter kept elsewhere with this stream's own."""
+        self._counters[counter.name] = counter
 
     def gauge(self, name: str) -> Gauge:
         return self._gauges.setdefault(name, Gauge(name))

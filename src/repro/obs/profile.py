@@ -1,4 +1,4 @@
-"""Span timers, `jax.profiler` wiring, and the retrace sentinel
+"""Span timers, garbage-collection spans and the retrace sentinel
 (DESIGN.md §12).
 
 `span` is the workhorse: a context manager timing a named region on the
@@ -6,11 +6,13 @@ host clock, mirrored into `jax.profiler.TraceAnnotation` so the same names
 line up in a TensorBoard/XPlane trace when one is being captured, and
 emitted as a ``span`` event when an `Obs` log is attached.  Module-level
 totals (`span_totals`) survive without any log so ad-hoc scripts can print
-a breakdown.
+a breakdown.  Each span knows its parent (the span open around it in the
+same thread) and a round index (its own, else its parent's); the last
+`RECENT_SPANS` finished spans stay in memory (`recent_spans`), so a
+caller can take one slow round apart after the fact.
 
-`annotate` wraps `jax.profiler.annotate_function` for the jitted round-step
-paths (the Pallas-vs-lax comparison shows up as named regions in a device
-trace); `profiler_trace` scopes a full `jax.profiler.trace` capture.
+`gc_spans` records each Python garbage collection inside its block as a
+span of its own, in memory and on the trace.
 
 `RetraceSentinel` watches the fleet/serve scans' ``_cache_size()`` deltas
 at runtime: chunked controller sweeps are DESIGNED to hit the jit cache
@@ -21,15 +23,39 @@ silent 100x slowdown ride to the end of the run.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
+import gc
 import logging
+import threading
 import time
-from typing import Callable
+from typing import Callable, NamedTuple
 
 logger = logging.getLogger("repro.obs")
 
 # name -> [count, total_ms]; the no-log fallback store
 _SPAN_TOTALS: dict[str, list] = {}
+RECENT_SPANS = 8192
+
+
+class SpanRecord(NamedTuple):
+    """One finished span; times in `time.perf_counter` seconds."""
+
+    name: str
+    start: float
+    end: float
+    parent: str | None
+    round: int | None
+
+
+_RECENT: collections.deque = collections.deque(maxlen=RECENT_SPANS)
+_OPEN = threading.local()       # .stack: [(name, round)] of open spans
+
+
+def _open_spans() -> list:
+    if not hasattr(_OPEN, "stack"):
+        _OPEN.stack = []
+    return _OPEN.stack
 
 
 def span_totals() -> dict[str, dict]:
@@ -38,54 +64,86 @@ def span_totals() -> dict[str, dict]:
             for k, v in _SPAN_TOTALS.items()}
 
 
+def recent_spans() -> list[SpanRecord]:
+    """The last `RECENT_SPANS` finished spans, oldest first."""
+    return list(_RECENT)
+
+
 def reset_spans() -> None:
     _SPAN_TOTALS.clear()
+    _RECENT.clear()
 
 
-@contextlib.contextmanager
-def span(name: str, obs=None):
-    """``with span("round_step"):`` — host wall time + profiler annotation.
-
-    Emits ``{"kind": "span", "name": ..., "ms": ...}`` to ``obs`` (when
-    given) on exit and always folds into `span_totals`.  Never raises from
-    instrumentation: a missing profiler backend degrades to timing only.
-    """
+def _annotation(name: str, **meta):
     try:
         import jax.profiler
-        annotation = jax.profiler.TraceAnnotation(name)
+        return jax.profiler.TraceAnnotation(name, **meta)
     except Exception:                                    # pragma: no cover
-        annotation = contextlib.nullcontext()
-    t0 = time.perf_counter()
-    with annotation:
-        yield
-    ms = (time.perf_counter() - t0) * 1e3
+        return contextlib.nullcontext()
+
+
+def _finish(name: str, t0: float, t1: float, parent, rnd, obs) -> float:
+    ms = (t1 - t0) * 1e3
     agg = _SPAN_TOTALS.setdefault(name, [0, 0.0])
     agg[0] += 1
     agg[1] += ms
+    _RECENT.append(SpanRecord(name, t0, t1, parent, rnd))
     if obs is not None:
         obs.event("span", name=name, ms=round(ms, 3))
-
-
-def annotate(name: str) -> Callable:
-    """Decorator: name a traced function in device profiles
-    (`jax.profiler.annotate_function`); identity when unavailable."""
-    try:
-        import jax.profiler
-        return jax.profiler.annotate_function(name=name)
-    except Exception:                                    # pragma: no cover
-        return lambda fn: fn
+    return ms
 
 
 @contextlib.contextmanager
-def profiler_trace(log_dir: str | None):
-    """Scope a `jax.profiler.trace` capture over a region; ``None`` is a
-    no-op so callers can thread an optional ``--profile-dir`` straight in."""
-    if not log_dir:
+def span(name: str, obs=None, *, round: int | None = None):
+    """``with span("round_step"):`` — host wall time + profiler annotation.
+
+    Emits ``{"kind": "span", "name": ..., "ms": ...}`` to ``obs`` (when
+    given) on exit, always folds into `span_totals` and `recent_spans`.
+    ``round`` rides on the profiler annotation too.  Never raises from
+    instrumentation: a missing profiler backend degrades to timing only.
+    """
+    stack = _open_spans()
+    parent, parent_round = stack[-1] if stack else (None, None)
+    rnd = parent_round if round is None else round
+    annotation = _annotation(name) if round is None else \
+        _annotation(name, round=round)
+    stack.append((name, rnd))
+    t0 = time.perf_counter()
+    try:
+        with annotation:
+            yield
+    finally:
+        t1 = time.perf_counter()
+        stack.pop()
+    _finish(name, t0, t1, parent, rnd, obs)
+
+
+@contextlib.contextmanager
+def gc_spans(name: str, on_collect: Callable[[float], None]):
+    """Inside the block, each Python garbage collection becomes a span
+    ``name`` (in `recent_spans`, `span_totals` and the profiler trace),
+    child of the span open in the thread that collected;
+    ``on_collect(ms)`` follows each one."""
+    started: list = []
+
+    def hook(phase, info):
+        if phase == "start":
+            stack = _open_spans()
+            parent, rnd = stack[-1] if stack else (None, None)
+            annotation = _annotation(name)
+            annotation.__enter__()
+            started.append((annotation, parent, rnd, time.perf_counter()))
+        elif started:
+            annotation, parent, rnd, t0 = started.pop()
+            t1 = time.perf_counter()
+            annotation.__exit__(None, None, None)
+            on_collect(_finish(name, t0, t1, parent, rnd, None))
+
+    gc.callbacks.append(hook)
+    try:
         yield
-        return
-    import jax.profiler
-    with jax.profiler.trace(log_dir):
-        yield
+    finally:
+        gc.callbacks.remove(hook)
 
 
 def _default_watch() -> dict[str, Callable[[], int]]:

@@ -75,6 +75,7 @@ def summarize(events: list[dict]) -> dict:
     retraces: list[dict] = []
     resumes: list[dict] = []
     hist_counts: dict[str, dict[str, int]] = {}
+    counters: dict = {}
     for e in events:
         if e["kind"] == "round":
             rounds.setdefault(e.get("scan", "?"), []).append(e)
@@ -89,6 +90,8 @@ def summarize(events: list[dict]) -> dict:
         elif e["kind"] == "hist":
             per = hist_counts.setdefault(e.get("scan", "?"), {})
             per[e["name"]] = per.get(e["name"], 0) + 1
+        elif e["kind"] == "metrics":
+            counters = dict(e.get("counters") or {})
 
     scan_stats = {}
     for scan, evs in rounds.items():
@@ -120,6 +123,7 @@ def summarize(events: list[dict]) -> dict:
         "retrace_warnings": retraces,
         "resumes": resumes,
         "hists": hist_counts,
+        "counters": counters,
         "events": len(events),
     }
 
@@ -169,6 +173,17 @@ def render_summary(summary: dict) -> str:
                  f"{s['mean_ms']:.3f}"]
                 for name, s in sorted(summary["spans"].items())]
         out.append(_fmt_table(["span", "count", "total ms", "mean ms"], rows))
+    counters = summary.get("counters") or {}
+    if counters:
+        out.append("\ncounters:")
+        out.append(_fmt_table(["counter", "value"],
+                              [[k, f"{v:.6g}"]
+                               for k, v in sorted(counters.items())]))
+        if counters.get("train.client_steps_computed"):
+            share = (counters.get("train.client_steps_useful", 0)
+                     / counters["train.client_steps_computed"])
+            out.append(f"  useful share of computed client steps: "
+                       f"{100 * share:.2f}%")
     if summary["controls"]:
         out.append("\ncontrol trajectory:")
         rows = [[c.get("round"), c.get("T"), c.get("E_mean"),

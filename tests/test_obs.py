@@ -528,3 +528,96 @@ def test_report_cli_summary_and_bench_diff(tmp_path):
                         "--sections", "round_step"], cwd=_REPO)
         assert out.returncode == 1 and "FAILED" in out.stdout
         assert "unfused_ms" in out.stdout
+
+
+# ------------------------------------------- span records, gc, counters --
+
+def test_span_records_parent_and_inherited_round():
+    from repro.obs import recent_spans, reset_spans, span, span_totals
+    reset_spans()
+    with span("outer", round=7):
+        with span("inner"):
+            pass
+    with span("lone"):
+        pass
+    recs = recent_spans()
+    assert [(r.name, r.parent, r.round) for r in recs] == [
+        ("inner", "outer", 7), ("outer", None, 7), ("lone", None, None)]
+    outer, inner = recs[1], recs[0]
+    assert outer.start <= inner.start <= inner.end <= outer.end
+    assert span_totals()["outer"]["count"] == 1
+    reset_spans()
+    assert recent_spans() == [] and span_totals() == {}
+
+
+def test_span_record_is_bounded_and_survives_an_exception():
+    from repro.obs import profile
+    profile.reset_spans()
+    for i in range(profile.RECENT_SPANS + 5):
+        with profile.span("s", round=i):
+            pass
+    recs = profile.recent_spans()
+    assert len(recs) == profile.RECENT_SPANS and recs[-1].round == \
+        profile.RECENT_SPANS + 4
+    with pytest.raises(RuntimeError):
+        with profile.span("boom"):
+            raise RuntimeError
+    with profile.span("after"):
+        pass
+    # the failed span's frame is gone: "after" has no parent
+    assert profile.recent_spans()[-1].parent is None
+    profile.reset_spans()
+
+
+def test_gc_spans_record_each_collection_under_the_open_span():
+    import gc
+    from repro.obs import gc_spans, recent_spans, reset_spans, span
+    reset_spans()
+    seen = []
+    with gc_spans("t.gc", seen.append):
+        with span("work", round=3):
+            gc.collect()
+    gc.collect()                      # outside the block: not recorded
+    gcs = [r for r in recent_spans() if r.name == "t.gc"]
+    assert len(gcs) == len(seen) >= 1
+    assert all(r.parent == "work" and r.round == 3 for r in gcs)
+    assert all(ms >= 0 for ms in seen)
+    reset_spans()
+
+
+def test_counter_totals_fold_every_counter_of_a_name(tmp_path):
+    from repro.obs import Counter, counter_totals, reset_counters
+    reset_counters()
+    a, b = Counter("x"), Counter("x")
+    a.inc(2)
+    b.inc()
+    assert (a.value, b.value) == (2, 1)
+    assert counter_totals() == {"x": 3}
+    obs = Obs(tmp_path)
+    obs.metrics.attach(a)
+    a.inc(5)
+    obs.close()
+    ev = [e for e in load_events(tmp_path / "events.jsonl")
+          if e["kind"] == "metrics"][-1]
+    assert ev["counters"]["x"] == 7
+    reset_counters()
+    assert counter_totals() == {}
+
+
+def test_report_summary_prints_counters_and_useful_share(tmp_path):
+    from repro.obs import render_summary
+    from repro.obs.metrics import Counter
+    obs = Obs(tmp_path)
+    obs.write_manifest("train", seed=0, num_clients=4, horizon=2)
+    for name, v in [("train.client_steps_computed", 16),
+                    ("train.client_steps_useful", 6),
+                    ("train.gc_ms", 12.5)]:
+        c = Counter(name)
+        c.inc(v)
+        obs.metrics.attach(c)
+    obs.close()
+    s = summarize(load_events(tmp_path / "events.jsonl"))
+    assert s["counters"]["train.client_steps_useful"] == 6
+    text = render_summary(s)
+    assert "train.gc_ms" in text and "12.5" in text
+    assert "useful share of computed client steps: 37.50%" in text
