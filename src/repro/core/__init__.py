@@ -30,6 +30,7 @@ from repro.core.round import (
     finish_sequential_round,
     local_update,
     parallel_round,
+    participant_round,
     run_rounds,
     sequential_client_step,
 )
@@ -43,6 +44,7 @@ __all__ = [
     "aggregate", "accumulate_client_delta", "apply_accumulated",
     "fedavg_aggregate", "scaled_delta_aggregate", "zeros_like_fp32",
     "FedConfig", "finish_sequential_round", "local_update", "parallel_round",
-    "run_rounds", "sequential_client_step", "Theorem1Constants",
+    "participant_round", "run_rounds", "sequential_client_step",
+    "Theorem1Constants",
     "SimResult", "simulate",
 ]

@@ -5,15 +5,22 @@ A *global round* is: (1) clients decide participation via the scheduling policy
 the current global model (eq. 7), (3) the server aggregates scaled deltas
 (eqs. 12-13) into the new global model.
 
-Two execution strategies over a TPU mesh (see DESIGN.md §3.2):
+Three schedules of the same round (see DESIGN.md §3.2); linearity of
+eq. (13) makes them equivalent:
 
-* **parallel** — all client groups run simultaneously: local models are stacked
-  on a leading client axis ``C`` that is sharded over the mesh's data axis.
-  The whole round is one jitted function; no communication during the local
-  phase, one fused weighted reduction at the end.
-* **sequential** — one client at a time over the full mesh (for architectures
-  whose parameters cannot be replicated per client group); linearity of
-  eq. (13) makes this exactly equivalent.
+* **parallel** (`parallel_round`) — all client groups run simultaneously:
+  local models are stacked on a leading client axis ``C`` that is sharded
+  over the mesh's data axis.  The whole round is one jitted function; no
+  communication during the local phase, one fused weighted reduction at the
+  end.  Every client computes; the mask zeroes non-participants.  Used by
+  the mesh step (`launch.steps.build_train_step`) and `run_rounds`.
+* **participants** (`participant_round`) — one jitted program that runs the
+  local update once per *participating* client, a loop whose trip count the
+  program reads off its own mask, folding each delta into an fp32
+  accumulator.  Used by the training launcher (`launch.train`).
+* **sequential** (`sequential_client_step`) — one client per call over the
+  full mesh (for architectures whose parameters cannot be replicated per
+  client group); the mesh step's sequential mode.
 
 The engine is model-agnostic: it takes a ``loss_fn(params, batch, rng)`` and an
 ``Optimizer``; everything else is pytrees.
@@ -106,25 +113,28 @@ def local_update(
 
     ``step_offset`` is the global schedule index of this round's first local
     step (round * T): Theorem 1's eta_t = 2/(mu(gamma+t)) must keep decaying
-    across rounds, not restart at eta_0 every round.
+    across rounds, not restart at eta_0 every round.  Step ``t``'s key is
+    ``fold_in(rng, t)``, as in `parallel_round`.
 
     Returns (local params after T steps, mean local loss).
     """
-    opt_state = optimizer.init(params)
+    with jax.named_scope("broadcast"):
+        opt_state = optimizer.init(params)
     vg = micro_value_and_grad(loss_fn, micro_batches, unroll=unroll)
 
     def step(carry, xs):
         p, s = carry
-        batch, key, t = xs
-        loss, grads = vg(p, batch, key)
-        p, s = optimizer.update(grads, s, p, t)
+        batch, t = xs
+        with jax.named_scope("local_step"):
+            loss, grads = vg(p, batch, jax.random.fold_in(rng, t))
+        with jax.named_scope("optimizer"):
+            p, s = optimizer.update(grads, s, p, t)
         return (p, s), loss
 
-    keys = jax.random.split(rng, num_steps)
     ts = jnp.asarray(step_offset, jnp.int32) \
         + jnp.arange(num_steps, dtype=jnp.int32)
     (params, _), losses = jax.lax.scan(step, (params, opt_state),
-                                       (batches, keys, ts), unroll=bool(unroll))
+                                       (batches, ts), unroll=bool(unroll))
     return params, jnp.mean(losses)
 
 
@@ -233,12 +243,78 @@ def sequential_client_step(
     else:
         scale_i = jnp.asarray(1.0, jnp.float32)  # eq. (9)
     coeff = jnp.asarray(alpha_i, jnp.float32) * jnp.asarray(p_i, jnp.float32) * scale_i
-    acc = aggregation.accumulate_client_delta(acc, w_local, w_global, coeff)
+    with jax.named_scope("aggregate"):
+        acc = aggregation.accumulate_client_delta(acc, w_local, w_global, coeff)
     return acc, loss
 
 
 def finish_sequential_round(cfg: FedConfig, w_global: PyTree, acc: PyTree) -> PyTree:
     return aggregation.apply_accumulated(w_global, acc, cfg.server_lr)
+
+
+def participant_round(
+    loss_fn: LossFn,
+    optimizer: Optimizer,
+    cfg: FedConfig,
+    w_global: PyTree,
+    client_batches: PyTree,   # leaves: (C, T, ...) per-client per-local-step minibatches
+    p: jax.Array,             # (C,) data weights p_i
+    E: jax.Array,             # (C,) energy renewal cycles
+    rnd: jax.Array,           # scalar int32 global round index
+    rng: jax.Array,
+) -> tuple[PyTree, dict[str, jax.Array]]:
+    """One global round that trains the round's participants only.
+
+    The same round as `parallel_round`, in one program: the participants'
+    indices come off the round's mask in ascending order, and a loop whose
+    trip count is their number ``k`` runs `sequential_client_step` for
+    each (client ``i``'s batches, key ``fold_in(rng, i)``, schedule index
+    ``rnd * T``), then `finish_sequential_round` applies the fp32
+    accumulator.  Non-participants cost nothing, so the round's work
+    follows the schedule with no capacity to choose and one compile.  A
+    round with no participants runs no trip and returns ``w_global`` as it
+    is.  Only the order of the fp32 sum over clients differs from
+    `parallel_round`.
+
+    Metrics: ``loss`` (mean of the participants' mean local losses, 0 with
+    none), ``participants`` (the mask's sum) and ``client_steps``
+    (``k * T``, the client steps computed).  Named scopes as in
+    `parallel_round`; ``broadcast`` holds a participant's batch gather and
+    optimizer init (DESIGN.md §12.3).
+    """
+    n, T = cfg.num_clients, cfg.local_steps
+    with jax.named_scope("schedule"):
+        mask = scheduling.participation_mask(cfg.policy, cfg.seed, rnd, E,
+                                             phase=cfg.phase_array())
+        (idx,) = jnp.nonzero(mask, size=n, fill_value=0)
+        k = jnp.sum(mask > 0, dtype=jnp.int32)
+    step_offset = jnp.asarray(rnd, jnp.int32) * T
+
+    def client(j, carry):
+        acc, loss_sum = carry
+        i = idx[j]
+        with jax.named_scope("broadcast"):
+            batches = jax.tree.map(
+                lambda b: jax.lax.dynamic_index_in_dim(b, i, keepdims=False),
+                client_batches)
+        acc, loss = sequential_client_step(
+            loss_fn, optimizer, cfg, w_global, acc, batches, p[i], E[i],
+            mask[i], jax.random.fold_in(rng, i), step_offset)
+        return acc, loss_sum + loss.astype(jnp.float32)
+
+    acc, loss_sum = jax.lax.fori_loop(
+        0, k, client, (aggregation.zeros_like_fp32(w_global), jnp.float32(0)))
+    with jax.named_scope("aggregate"):
+        # w + 0 would turn a -0.0 weight into +0.0: keep w as it is
+        w_new = jax.tree.map(lambda new, old: jnp.where(k > 0, new, old),
+                             finish_sequential_round(cfg, w_global, acc),
+                             w_global)
+    metrics = {
+        "loss": loss_sum / jnp.maximum(k, 1),
+        "participants": jnp.sum(mask),
+        "client_steps": k * T,
+    }
+    return w_new, metrics
 
 
 def run_rounds(

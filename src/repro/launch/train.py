@@ -26,7 +26,7 @@ import numpy as np
 
 from repro.checkpoint import save_checkpoint
 from repro.configs import get_config, get_smoke_config
-from repro.core import EnergyProfile, FedConfig, parallel_round
+from repro.core import EnergyProfile, FedConfig, participant_round
 from repro.data import SyntheticImages, SyntheticTokens, iid_partition, \
     FederatedLoader, client_weights
 from repro.launch.cache import enable_compile_cache
@@ -53,8 +53,9 @@ def token_batch_fn(cfg, source, C, T, bc):
     return fn
 
 
-# what `train_rounds` counts; ``train.client_steps_useful`` are the
-# participants' local steps (participants x T)
+# what `train_rounds` counts; ``train.client_steps_computed`` are the local
+# steps the round ran (its ``client_steps``), ``train.client_steps_useful``
+# the participants' (participants x T): equal under `participant_round`
 TRAIN_COUNTERS = ("train.rounds", "train.client_steps_computed",
                   "train.client_steps_useful", "train.gc_collections",
                   "train.gc_ms")
@@ -72,7 +73,7 @@ class TrainRun:
     E: jax.Array                    # (C,) energy renewal cycles
     rng: jax.Array
     batch_fn: Callable[[int], dict]  # round -> (C, T, ...) batches
-    round_fn: Callable               # jitted `parallel_round`
+    round_fn: Callable               # jitted `participant_round`
     counters: dict[str, Counter] = dataclasses.field(
         default_factory=lambda: {n: Counter(n) for n in TRAIN_COUNTERS})
 
@@ -84,7 +85,11 @@ def setup_training(cfg, *, clients: int, local_steps: int, batch: int,
                    seq: int, taus=(1, 2, 4, 8), policy: str = "sustainable",
                    optimizer: str = "adam", lr: float = 1e-3,
                    seed: int = 0) -> TrainRun:
-    """Model, schedule, data and the jitted round for one launcher run."""
+    """Model, schedule, data and the jitted round for one launcher run.
+
+    The round is `core.round.participant_round`: one program per process
+    that trains only the round's participants, a loop whose trip count it
+    reads off its own mask (DESIGN.md §3.2)."""
     model = get_model(cfg)
     C, T = clients, local_steps
     fed = FedConfig(num_clients=C, local_steps=T, policy=policy, seed=seed)
@@ -106,7 +111,7 @@ def setup_training(cfg, *, clients: int, local_steps: int, batch: int,
     return TrainRun(cfg=cfg, model=model, fed=fed, p=jnp.ones((C,)) / C,
                     E=EnergyProfile(C, tuple(taus)).cycles(),
                     rng=jax.random.PRNGKey(seed), batch_fn=batch_fn,
-                    round_fn=jax.jit(partial(parallel_round, loss_fn, opt,
+                    round_fn=jax.jit(partial(participant_round, loss_fn, opt,
                                              fed)))
 
 

@@ -1,10 +1,14 @@
-"""Round engine: parallel == sequential == by-hand local SGD + aggregation."""
+"""Round engine: parallel == sequential == participants only == by-hand
+local SGD + aggregation."""
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import (FedConfig, Policy, aggregate, parallel_round,
-                        participation_mask, local_update,
+                        participant_round, participation_mask, local_update,
                         accumulate_client_delta, apply_accumulated,
                         zeros_like_fp32, aggregation_scale)
 from repro.optim import adam, sgd
@@ -113,3 +117,54 @@ def test_adam_local_state_reset_each_round():
     # same inputs, different round index: identical result (no hidden state)
     for k in w1:
         np.testing.assert_allclose(np.asarray(w1[k]), np.asarray(w1b[k]))
+
+
+def _noisy_loss(p, batch, rng):
+    """_quad_loss plus a term drawn from the step's key, so that a round
+    matches another only if each client step gets the same key."""
+    x, y = batch
+    noise = jax.random.normal(rng, y.shape)
+    return _quad_loss(p, batch, rng) + 0.1 * jnp.mean(noise * (x @ p["w"]))
+
+
+OPTIMIZERS = {"sgd": lambda: sgd(0.1),
+              "sgd_momentum": lambda: sgd(0.05, momentum=0.9),
+              "adam": lambda: adam(1e-2)}
+
+
+@pytest.mark.parametrize("opt_name", sorted(OPTIMIZERS))
+@pytest.mark.parametrize("policy, rnd", [
+    ("sustainable", 2), ("greedy", 1), ("always", 3),
+    ("wait_all", 3), ("wait_all", 4)])
+def test_participant_round_equals_parallel(policy, rnd, opt_name):
+    """Training the participants only gives parallel_round's model and
+    metrics; it computes participants x T client steps, and a round
+    nobody takes part in (wait_all between sync points) returns the
+    global model bit for bit."""
+    C, T = 6, 3
+    w0, batches, p, E, key = _setup(C, T)
+    w0 = {"w": jnp.asarray([0.5, -0.25, 1.0]), "b": jnp.asarray(-0.0)}
+    cfg = FedConfig(num_clients=C, local_steps=T, policy=Policy(policy),
+                    seed=3)
+    opt = OPTIMIZERS[opt_name]()
+    args = (w0, batches, p, E, jnp.int32(rnd), key)
+    w_par, m_par = jax.jit(partial(parallel_round, _noisy_loss, opt, cfg))(
+        *args)
+    w_part, m_part = jax.jit(partial(participant_round, _noisy_loss, opt,
+                                     cfg))(*args)
+    k = float(m_part["participants"])
+    assert k == float(m_par["participants"])
+    assert int(m_part["client_steps"]) == k * T
+    for name in w0:
+        np.testing.assert_allclose(np.asarray(w_part[name]),
+                                   np.asarray(w_par[name]),
+                                   rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(float(m_part["loss"]), float(m_par["loss"]),
+                               rtol=1e-5, atol=1e-5)
+    if policy == "wait_all" and rnd % 3:
+        assert k == 0 and float(m_part["loss"]) == 0.0
+        for name in w0:
+            assert np.asarray(w_part[name]).tobytes() == \
+                np.asarray(w0[name]).tobytes()
+    else:
+        assert k > 0
