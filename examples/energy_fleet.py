@@ -80,7 +80,7 @@ b = jnp.linspace(-1.0, 1.0, C)
 
 
 def loss(params, batch, rng):
-    return 0.5 * jnp.sum((params["w"] - b[batch["client"]]) ** 2)
+    return 0.5 * jnp.sum((params["w"] - b[batch["client"]]) ** 2), {}
 
 
 def batch_fn(rnd, i):

@@ -37,7 +37,7 @@ def loss_fn(params, batch, rng):
     logits = mlp_apply(params, batch["images"])
     logz = jax.nn.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, batch["labels"][:, None], -1)[:, 0]
-    return jnp.mean(logz - gold)
+    return jnp.mean(logz - gold), {}
 
 
 def run(alpha, policy, rounds, C=16, T=5, batch=8, seed=0, noise=4.0):
@@ -65,7 +65,7 @@ def run(alpha, policy, rounds, C=16, T=5, batch=8, seed=0, noise=4.0):
     acc = float(jnp.mean(jnp.argmax(mlp_apply(res.params, jnp.asarray(xte)), -1)
                          == jnp.asarray(yte)))
     tl = float(loss_fn(res.params, {"images": jnp.asarray(xte),
-                                    "labels": jnp.asarray(yte)}, None))
+                                    "labels": jnp.asarray(yte)}, None)[0])
     return acc, tl
 
 

@@ -59,7 +59,7 @@ def main():
     def loss_fn(params, batch, rng):
         return model.loss_fn(params, batch)
 
-    eval_loss = jax.jit(lambda w: model.loss_fn(w, held_out))
+    eval_loss = jax.jit(lambda w: model.loss_fn(w, held_out)[0])
 
     def batch_fn(rnd, i):
         toks = np.stack([source.batch(i, a.batch, rnd * 131 + t)

@@ -28,6 +28,7 @@ class ModelConfig:
 
     # attention details
     qkv_bias: bool = False
+    qk_norm: bool = False            # RMSNorm over the full q and k projections
     rope_theta: float = 10000.0
     sliding_window: int = 0          # 0 = full causal attention
     mlp_type: str = "swiglu"         # swiglu | gelu
@@ -36,10 +37,16 @@ class ModelConfig:
     max_position: int = 524288       # for learned/sinusoidal tables
     logit_softcap: float = 0.0
 
-    # MoE
+    # MoE: the router scores ``router_experts`` (0 = num_experts); this
+    # chip holds ``num_experts`` of them, from ``expert_offset`` on
     num_experts: int = 0
     experts_per_token: int = 0
-    moe_mode: str = "dense"          # dense (compute-all) | dispatch (capacity)
+    router_experts: int = 0
+    expert_offset: int = 0
+    moe_norm_topk: bool = True       # renormalise the top-k router weights
+    moe_aux_coef: float = 0.01       # load-balancing loss weight
+    # dense | dispatch | sorted | sorted_local | dropless (models/moe.py)
+    moe_mode: str = "dense"
     capacity_factor: float = 1.25
 
     # SSM (Mamba2 / SSD)
@@ -103,6 +110,10 @@ class ModelConfig:
         return self.num_kv_heads * self.head_dim
 
     @property
+    def router_width(self) -> int:
+        return self.router_experts or self.num_experts
+
+    @property
     def ssm_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -129,6 +140,8 @@ class ModelConfig:
         attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
         if self.qkv_bias:
             attn += self.q_dim + 2 * self.kv_dim
+        if self.qk_norm:
+            attn += self.q_dim + self.kv_dim
         if self.mlp_type == "swiglu":
             mlp = 3 * d * ff
         else:
@@ -136,7 +149,8 @@ class ModelConfig:
         norms = 2 * d
         per_dense = attn + mlp + norms
         if self.family == "moe":
-            per = attn + norms + d * self.num_experts + self.num_experts * 3 * d * ff
+            per = (attn + norms + d * self.router_width
+                   + self.num_experts * 3 * d * ff)
             return emb + self.num_layers * per
         if self.family == "hybrid":
             pat = self.block_pattern or ("rglru",)
@@ -161,13 +175,15 @@ class ModelConfig:
         return emb + self.num_layers * per_dense
 
     def num_active_params(self) -> int:
-        """Active params per token (MoE top-k)."""
+        """Active params per token (MoE top-k): of the experts held here, a
+        token is expected to reach ``k * held / router_experts``."""
         if self.family != "moe":
             return self.num_params()
         d, ff = self.d_model, self.d_ff
         total = self.num_params()
         all_exp = self.num_layers * self.num_experts * 3 * d * ff
-        act_exp = self.num_layers * self.experts_per_token * 3 * d * ff
+        act_exp = (self.num_layers * self.experts_per_token * self.num_experts
+                   * 3 * d * ff // self.router_width)
         return total - all_exp + act_exp
 
 

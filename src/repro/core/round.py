@@ -22,8 +22,10 @@ eq. (13) makes them equivalent:
   full mesh (for architectures whose parameters cannot be replicated per
   client group); the mesh step's sequential mode.
 
-The engine is model-agnostic: it takes a ``loss_fn(params, batch, rng)`` and an
-``Optimizer``; everything else is pytrees.
+The engine is model-agnostic: it takes a ``loss_fn(params, batch, rng) ->
+(loss, stats)``, ``stats`` a dict of the step's counts (empty for a model
+that counts nothing; `models.api`), and an ``Optimizer``; everything else is
+pytrees.
 """
 from __future__ import annotations
 
@@ -38,16 +40,19 @@ from repro.core import aggregation, scheduling
 from repro.optim import Optimizer
 
 PyTree = Any
-LossFn = Callable[[PyTree, PyTree, jax.Array], jax.Array]
+LossFn = Callable[[PyTree, PyTree, jax.Array], tuple[jax.Array, dict]]
 
 
 def micro_value_and_grad(loss_fn: LossFn, num_micro: int,
                          unroll: bool = False):
-    """value_and_grad with gradient accumulation over ``num_micro`` splits of
-    the batch's leading dim (peak-activation memory / num_micro; fp32 accum).
+    """``jax.value_and_grad(loss_fn, has_aux=True)``, ``(params, batch, key)
+    -> ((loss, stats), grads)``, with gradient accumulation over
+    ``num_micro`` splits of the batch's leading dim (peak-activation memory
+    / num_micro; fp32 accum) and the stats summed over the splits.
     """
+    vg = jax.value_and_grad(loss_fn, has_aux=True)
     if num_micro <= 1:
-        return jax.value_and_grad(loss_fn)
+        return vg
 
     def f(params, batch, key):
         for leaf in jax.tree.leaves(batch):
@@ -63,18 +68,23 @@ def micro_value_and_grad(loss_fn: LossFn, num_micro: int,
 
         def step(carry, xs):
             acc_l, acc_g = carry
-            l, g = jax.value_and_grad(loss_fn)(params, xs, key)
+            (l, stats), g = vg(params, xs, key)
             acc_g = jax.tree.map(
                 lambda a, x: a + x.astype(jnp.float32) / num_micro, acc_g, g)
-            return (acc_l + l / num_micro, acc_g), None
+            return (acc_l + l / num_micro, acc_g), stats
 
         zeros = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), params)
-        (loss, grads), _ = jax.lax.scan(step, (jnp.float32(0), zeros), mb,
-                                        unroll=bool(unroll))
+        (loss, grads), stats = jax.lax.scan(step, (jnp.float32(0), zeros), mb,
+                                            unroll=bool(unroll))
         grads = jax.tree.map(lambda g, p: g.astype(p.dtype), grads, params)
-        return loss, grads
+        return (loss, _summed(stats)), grads
 
     return f
+
+
+def _summed(stats):
+    """Stats stacked on a leading axis (steps, splits) -> their sums."""
+    return jax.tree.map(lambda x: jnp.sum(x, axis=0), stats)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -105,7 +115,7 @@ def local_update(
     unroll: bool = False,
     micro_batches: int = 1,
     step_offset: jax.Array | int = 0,
-) -> tuple[PyTree, jax.Array]:
+) -> tuple[PyTree, jax.Array, dict]:
     """Eq. (7): ``T`` local optimizer steps via lax.scan.
 
     The local optimizer state is freshly initialised each round (FedAvg
@@ -116,7 +126,8 @@ def local_update(
     across rounds, not restart at eta_0 every round.  Step ``t``'s key is
     ``fold_in(rng, t)``, as in `parallel_round`.
 
-    Returns (local params after T steps, mean local loss).
+    Returns (local params after T steps, mean local loss, the loss's stats
+    summed over the steps).
     """
     with jax.named_scope("broadcast"):
         opt_state = optimizer.init(params)
@@ -126,16 +137,16 @@ def local_update(
         p, s = carry
         batch, t = xs
         with jax.named_scope("local_step"):
-            loss, grads = vg(p, batch, jax.random.fold_in(rng, t))
+            (loss, stats), grads = vg(p, batch, jax.random.fold_in(rng, t))
         with jax.named_scope("optimizer"):
             p, s = optimizer.update(grads, s, p, t)
-        return (p, s), loss
+        return (p, s), (loss, stats)
 
     ts = jnp.asarray(step_offset, jnp.int32) \
         + jnp.arange(num_steps, dtype=jnp.int32)
-    (params, _), losses = jax.lax.scan(step, (params, opt_state),
-                                       (batches, ts), unroll=bool(unroll))
-    return params, jnp.mean(losses)
+    (params, _), (losses, stats) = jax.lax.scan(
+        step, (params, opt_state), (batches, ts), unroll=bool(unroll))
+    return params, jnp.mean(losses), _summed(stats)
 
 
 def parallel_round(
@@ -168,7 +179,8 @@ def parallel_round(
 
     Named scopes ``schedule``, ``broadcast``, ``local_step``, ``optimizer``
     and ``aggregate`` tag the round's ops for a device trace (DESIGN.md
-    §12); they change op metadata only.
+    §12); they change op metadata only.  The loss's stats are not among the
+    metrics: they would count the masked clients' steps too.
     """
     n = cfg.num_clients
     cst = constrain if constrain is not None else (lambda t: t)
@@ -195,7 +207,7 @@ def parallel_round(
         batch, t = inp
         kt = jax.vmap(lambda k: jax.random.fold_in(k, t))(keys)
         with jax.named_scope("local_step"):
-            losses, grads = jax.vmap(vg)(w, batch, kt)
+            (losses, _), grads = jax.vmap(vg)(w, batch, kt)
         with jax.named_scope("optimizer"):
             w, s = optimizer.update(grads, s, w, t)
         return (cst(w), cst_opt(s)), losses
@@ -231,13 +243,15 @@ def sequential_client_step(
     alpha_i: jax.Array,       # this client's participation bit for this round
     rng: jax.Array,
     step_offset: jax.Array | int = 0,   # round * T, global schedule index
-) -> tuple[PyTree, jax.Array]:
+) -> tuple[PyTree, jax.Array, dict]:
     """Sequential mode: process ONE client's local round and fold its scaled
-    delta into the accumulator.  ``apply_accumulated`` finishes the round."""
-    w_local, loss = local_update(loss_fn, optimizer, w_global, batches, rng,
-                                 cfg.local_steps, unroll=cfg.unroll,
-                                 micro_batches=cfg.micro_batches,
-                                 step_offset=step_offset)
+    delta into the accumulator.  ``apply_accumulated`` finishes the round.
+    Returns (accumulator, mean local loss, the loss's stats summed over the
+    client's steps)."""
+    w_local, loss, stats = local_update(
+        loss_fn, optimizer, w_global, batches, rng, cfg.local_steps,
+        unroll=cfg.unroll, micro_batches=cfg.micro_batches,
+        step_offset=step_offset)
     if scheduling.Policy(cfg.policy) == scheduling.Policy.SUSTAINABLE:
         scale_i = jnp.asarray(E_i, jnp.float32)  # eq. (12)
     else:
@@ -245,7 +259,7 @@ def sequential_client_step(
     coeff = jnp.asarray(alpha_i, jnp.float32) * jnp.asarray(p_i, jnp.float32) * scale_i
     with jax.named_scope("aggregate"):
         acc = aggregation.accumulate_client_delta(acc, w_local, w_global, coeff)
-    return acc, loss
+    return acc, loss, stats
 
 
 def finish_sequential_round(cfg: FedConfig, w_global: PyTree, acc: PyTree) -> PyTree:
@@ -277,10 +291,11 @@ def participant_round(
     `parallel_round`.
 
     Metrics: ``loss`` (mean of the participants' mean local losses, 0 with
-    none), ``participants`` (the mask's sum) and ``client_steps``
-    (``k * T``, the client steps computed).  Named scopes as in
-    `parallel_round`; ``broadcast`` holds a participant's batch gather and
-    optimizer init (DESIGN.md §12.3).
+    none), ``participants`` (the mask's sum), ``client_steps`` (``k * T``,
+    the client steps computed) and the loss's stats, each summed over the
+    participants' steps; empty stats add none.  Named
+    scopes as in `parallel_round`; ``broadcast`` holds a participant's
+    batch gather and optimizer init (DESIGN.md §12.3).
     """
     n, T = cfg.num_clients, cfg.local_steps
     with jax.named_scope("schedule"):
@@ -289,21 +304,29 @@ def participant_round(
         (idx,) = jnp.nonzero(mask, size=n, fill_value=0)
         k = jnp.sum(mask > 0, dtype=jnp.int32)
     step_offset = jnp.asarray(rnd, jnp.int32) * T
+    # the stats' shapes, from one step's loss traced abstractly: no op
+    stats0 = jax.tree.map(
+        lambda s: jnp.zeros(s.shape, s.dtype),
+        jax.eval_shape(lambda: loss_fn(
+            w_global, jax.tree.map(lambda b: b[0, 0], client_batches),
+            rng)[1]))
 
     def client(j, carry):
-        acc, loss_sum = carry
+        acc, loss_sum, stats_sum = carry
         i = idx[j]
         with jax.named_scope("broadcast"):
             batches = jax.tree.map(
                 lambda b: jax.lax.dynamic_index_in_dim(b, i, keepdims=False),
                 client_batches)
-        acc, loss = sequential_client_step(
+        acc, loss, stats = sequential_client_step(
             loss_fn, optimizer, cfg, w_global, acc, batches, p[i], E[i],
             mask[i], jax.random.fold_in(rng, i), step_offset)
-        return acc, loss_sum + loss.astype(jnp.float32)
+        return (acc, loss_sum + loss.astype(jnp.float32),
+                jax.tree.map(jnp.add, stats_sum, stats))
 
-    acc, loss_sum = jax.lax.fori_loop(
-        0, k, client, (aggregation.zeros_like_fp32(w_global), jnp.float32(0)))
+    acc, loss_sum, stats = jax.lax.fori_loop(
+        0, k, client,
+        (aggregation.zeros_like_fp32(w_global), jnp.float32(0), stats0))
     with jax.named_scope("aggregate"):
         # w + 0 would turn a -0.0 weight into +0.0: keep w as it is
         w_new = jax.tree.map(lambda new, old: jnp.where(k > 0, new, old),
@@ -313,6 +336,7 @@ def participant_round(
         "loss": loss_sum / jnp.maximum(k, 1),
         "participants": jnp.sum(mask),
         "client_steps": k * T,
+        **stats,
     }
     return w_new, metrics
 

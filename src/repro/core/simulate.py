@@ -146,7 +146,7 @@ def simulate(
                 key = jax.random.fold_in(jax.random.fold_in(rng, r), int(i))
                 batch = (batch_fn(r, int(i), T_r) if batch_takes_steps
                          else batch_fn(r, int(i)))
-                w_i, loss = local(w, batch, key,
+                w_i, loss, _ = local(w, batch, key,
                                   step_offset=jnp.int32(local_steps_done))
                 coeff = float(p[i] * scale[i])
                 acc = aggregation.accumulate_client_delta(acc, w_i, w, coeff)

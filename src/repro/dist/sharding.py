@@ -35,11 +35,12 @@ PyTree = Any
 
 MODEL_AXIS = "model"
 
-# Leaf names that are always replicated: norm scales/biases, projection
-# biases, per-head scalar vectors (A_log, D, dt_bias, lambda).  They are tiny,
-# and replicating them keeps elementwise ops collective-free.
+# Leaf names that are always replicated: norm scales/biases (QK-norm's
+# too), projection biases, per-head scalar vectors (A_log, D, dt_bias,
+# lambda).  They are tiny, and replicating them keeps elementwise ops
+# collective-free.
 _REPLICATED = {
-    "scale", "bias", "norm", "lam",
+    "scale", "bias", "norm", "lam", "q_norm", "k_norm",
     "b", "bq", "bk", "bv", "bi", "bo", "ba", "conv_b",
     "a_log", "d", "dt_bias",
 }

@@ -169,7 +169,7 @@ def build_train_step(cfg: ModelConfig, shape: InputShape, mesh,
             _batch_shardings(batches, mesh, 1, shape.global_batch),
             _repl(mesh), _repl(mesh), _repl(mesh), _repl(mesh), _repl(mesh),
         )
-        out_sh = (p_sh, _repl(mesh))
+        out_sh = (p_sh, _repl(mesh), _repl(mesh))   # acc, loss, stats
         fn = partial(sequential_client_step, loss_fn, opt, fed)
         meta = dict(mode="sequential", local_steps=local_steps,
                     micro_batches=cfg.micro_batches)

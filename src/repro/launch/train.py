@@ -32,6 +32,7 @@ from repro.data import SyntheticImages, SyntheticTokens, iid_partition, \
 from repro.launch.cache import enable_compile_cache
 from repro.launch.steps import make_optimizer_for
 from repro.models import get_model
+from repro.models import moe as moe_mod
 from repro.obs.metrics import Counter
 from repro.obs.profile import gc_spans, span
 
@@ -61,6 +62,13 @@ TRAIN_COUNTERS = ("train.rounds", "train.client_steps_computed",
                   "train.gc_ms")
 
 
+def _counters(stats=()) -> dict[str, Counter]:
+    """`TRAIN_COUNTERS`, and ``train.<stat>`` for each stat the round
+    returns (the dropless expert layer's `moe.STATS`)."""
+    return {n: Counter(n)
+            for n in TRAIN_COUNTERS + tuple(f"train.{s}" for s in stats)}
+
+
 @dataclasses.dataclass
 class TrainRun:
     """Everything one federated training run needs, built by
@@ -74,8 +82,8 @@ class TrainRun:
     rng: jax.Array
     batch_fn: Callable[[int], dict]  # round -> (C, T, ...) batches
     round_fn: Callable               # jitted `participant_round`
-    counters: dict[str, Counter] = dataclasses.field(
-        default_factory=lambda: {n: Counter(n) for n in TRAIN_COUNTERS})
+    stats: tuple[str, ...] = ()      # the model's stats among its metrics
+    counters: dict[str, Counter] = dataclasses.field(default_factory=_counters)
 
     def init_params(self):
         return self.model.init_params(self.rng)
@@ -89,11 +97,13 @@ def setup_training(cfg, *, clients: int, local_steps: int, batch: int,
 
     The round is `core.round.participant_round`: one program per process
     that trains only the round's participants, a loop whose trip count it
-    reads off its own mask (DESIGN.md §3.2)."""
+    reads off its own mask (DESIGN.md §3.2).  The loss carries the model's
+    stats out of the round, summed over the participants' steps."""
     model = get_model(cfg)
     C, T = clients, local_steps
     fed = FedConfig(num_clients=C, local_steps=T, policy=policy, seed=seed)
     opt = make_optimizer_for(cfg, optimizer, lr)
+    stats = moe_mod.STATS if moe_mod.counts_stats(cfg) else ()
 
     def loss_fn(params, batch, key):
         return model.loss_fn(params, batch)
@@ -112,7 +122,8 @@ def setup_training(cfg, *, clients: int, local_steps: int, batch: int,
                     E=EnergyProfile(C, tuple(taus)).cycles(),
                     rng=jax.random.PRNGKey(seed), batch_fn=batch_fn,
                     round_fn=jax.jit(partial(participant_round, loss_fn, opt,
-                                             fed)))
+                                             fed)),
+                    stats=stats, counters=_counters(stats))
 
 
 def train_rounds(run: TrainRun, w, rounds: int, *, start: int = 0,
@@ -128,7 +139,8 @@ def train_rounds(run: TrainRun, w, rounds: int, *, start: int = 0,
     its index) tiled by ``train.batch``, ``train.args``, ``train.dispatch``,
     ``train.fetch`` and ``train.after_round``; each garbage collection is
     a ``train.gc`` span; ``run.counters`` count the rounds, the client
-    steps computed and those of participants, and the collections.  With
+    steps computed and those of participants, the collections, and each
+    of ``run.stats`` as ``train.<stat>``.  With
     ``obs`` the spans are events too and the counters ride on its closing
     ``metrics`` event."""
     history = [] if history is None else history
@@ -161,6 +173,9 @@ def train_rounds(run: TrainRun, w, rounds: int, *, start: int = 0,
                         int(m["client_steps"]))
                     count["train.client_steps_useful"].inc(
                         int(rec["participants"]) * T)
+                    for s in run.stats:
+                        if s in m:   # `parallel_round` drops the stats
+                            count[f"train.{s}"].inc(float(m[s]))
                     if obs is not None:
                         obs.event("round", scan="train", **rec)
                 with span("train.after_round", obs):

@@ -3,7 +3,9 @@
 Every family exposes:
   init_params(cfg, rng) -> params
   forward(cfg, params, batch, impl) -> (logits, aux)
-  loss_fn(cfg, params, batch, rng, impl) -> scalar
+  loss_fn(cfg, params, batch, rng, impl) -> (scalar, stats)
+      stats: a dict of the step's counts (the dropless expert layer's,
+      `moe.STATS`); empty for families that count nothing
   init_cache(cfg, batch, cache_len) -> cache            (decoder families)
   prefill(cfg, params, batch, cache_len, impl, window) -> (logits, cache)
   decode_step(cfg, params, token, cache, pos, ring, window, impl) -> (logits, cache)

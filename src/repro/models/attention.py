@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import apply_rope, dtype_of
+from repro.models.layers import apply_rope, dtype_of, rmsnorm
 
 NEG_INF = -1e30
 
@@ -36,11 +36,29 @@ def attn_init(cfg: ModelConfig, rng, shape_prefix=(), cross: bool = False):
         p["bq"] = jnp.zeros(shape_prefix + (qd,), dt)
         p["bk"] = jnp.zeros(shape_prefix + (kvd,), dt)
         p["bv"] = jnp.zeros(shape_prefix + (kvd,), dt)
+    if cfg.qk_norm:
+        p["q_norm"] = jnp.zeros(shape_prefix + (qd,), jnp.float32)
+        p["k_norm"] = jnp.zeros(shape_prefix + (kvd,), jnp.float32)
     return p
 
 
 def _split_heads(x, n_heads, head_dim):
     return x.reshape(x.shape[:-1] + (n_heads, head_dim))
+
+
+def _project(cfg: ModelConfig, p, x, src):
+    """q from ``x``, k and v from ``src``, split into heads; with
+    ``qk_norm`` the full q and k projections are RMS-normalised first
+    (OLMoE), before any rotary positions."""
+    q = x @ p["wq"] + p.get("bq", 0)
+    k = src @ p["wk"] + p.get("bk", 0)
+    v = src @ p["wv"] + p.get("bv", 0)
+    if cfg.qk_norm:
+        q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
+        k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
+    return (_split_heads(q, cfg.num_heads, cfg.head_dim),
+            _split_heads(k, cfg.num_kv_heads, cfg.head_dim),
+            _split_heads(v, cfg.num_kv_heads, cfg.head_dim))
 
 
 def repeat_kv(k, num_heads):
@@ -148,10 +166,7 @@ def attention(cfg: ModelConfig, p, x, *, positions=None, causal=True,
     """
     B, S, _ = x.shape
     win = cfg.sliding_window if window is None else window
-    q = _split_heads(x @ p["wq"] + p.get("bq", 0), cfg.num_heads, cfg.head_dim)
-    src = x if memory is None else memory
-    k = _split_heads(src @ p["wk"] + p.get("bk", 0), cfg.num_kv_heads, cfg.head_dim)
-    v = _split_heads(src @ p["wv"] + p.get("bv", 0), cfg.num_kv_heads, cfg.head_dim)
+    q, k, v = _project(cfg, p, x, x if memory is None else memory)
     if positions is None:
         positions = jnp.arange(S)
     if cfg.pos_type == "rope" and memory is None:
@@ -201,9 +216,7 @@ def decode_attention(cfg: ModelConfig, p, x, cache, pos, *, ring: bool,
     """
     B = x.shape[0]
     win = cfg.sliding_window if window is None else window
-    q = _split_heads(x @ p["wq"] + p.get("bq", 0), cfg.num_heads, cfg.head_dim)
-    k1 = _split_heads(x @ p["wk"] + p.get("bk", 0), cfg.num_kv_heads, cfg.head_dim)
-    v1 = _split_heads(x @ p["wv"] + p.get("bv", 0), cfg.num_kv_heads, cfg.head_dim)
+    q, k1, v1 = _project(cfg, p, x, x)
     posv = jnp.full((1,), pos)
     if cfg.pos_type == "rope":
         q = apply_rope(q, posv, cfg.rope_theta)

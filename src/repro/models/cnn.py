@@ -56,7 +56,7 @@ def loss_fn(cfg: ModelConfig, params, batch, rng=None, impl: str = "ref"):
     labels = batch["labels"]
     logz = jax.nn.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
-    return jnp.mean(logz - gold)
+    return jnp.mean(logz - gold), {}
 
 
 def accuracy(params, batch):

@@ -92,7 +92,7 @@ def forward(cfg: ModelConfig, params, batch, impl: str = "ref",
 def loss_fn(cfg: ModelConfig, params, batch, rng=None, impl: str = "ref"):
     logits, _ = forward(cfg, params, batch, impl=impl, padded_logits=True)
     return L.softmax_xent(logits[:, :-1], batch["tokens"][:, 1:],
-                          valid_vocab=cfg.vocab_size)
+                          valid_vocab=cfg.vocab_size), {}
 
 
 # ------------------------------------------------------------- serving -----

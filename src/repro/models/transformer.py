@@ -2,7 +2,10 @@
 
 Covers: qwen1.5-4b, granite-3-2b, granite-8b, starcoder2-7b (dense),
 mixtral-8x7b, olmoe-1b-7b (moe), internvl2-76b (vlm = dense trunk + stub
-vision embeddings spliced into the prefix).
+vision embeddings spliced into the prefix).  The moe family's expert layer
+runs in ``cfg.moe_mode`` (`models/moe.py`): Mixtral in ``dense``, OLMoE in
+``dropless``, the chip's share of an expert-parallel layer, whose stats
+`loss_fn` returns; ``qk_norm`` gives OLMoE's QK-norm.
 
 Layers are param-stacked (leading L axis) and executed with ``jax.lax.scan``
 (+ optional per-layer remat) so the lowered HLO is layer-count independent —
@@ -69,7 +72,9 @@ def _layer(cfg: ModelConfig, p, x, positions, impl):
 
 def forward(cfg: ModelConfig, params, batch, impl: str = "ref",
             padded_logits: bool = False):
-    """batch: {tokens (B,S) int32, [vision_embeds (B,n_vis,d)]} -> (logits, aux)."""
+    """batch: {tokens (B,S) int32, [vision_embeds (B,n_vis,d)]} -> (logits,
+    aux): aux is (load-balancing loss, stats), `moe.layer_aux` of the
+    layers' stacked outputs."""
     tokens = batch["tokens"]
     x = L.embed_tokens(cfg, params["embed"], tokens)
     x = _splice_vision(cfg, x, batch.get("vision_embeds"))
@@ -85,23 +90,27 @@ def forward(cfg: ModelConfig, params, batch, impl: str = "ref",
             return h, aux
         x, auxs = jax.lax.scan(scan_fn, x, params["layers"],
                                unroll=bool(cfg.scan_unroll))
-        aux = jnp.sum(auxs)
     else:
-        aux = jnp.float32(0)
+        per_layer = []
         for i in range(cfg.num_layers):
             layer_p = jax.tree.map(lambda a: a[i], params["layers"])
             x, a = body(layer_p, x)
-            aux = aux + a
+            per_layer.append(a)
+        auxs = jax.tree.map(lambda *a: jnp.stack(a), *per_layer)
+    aux = moe_mod.layer_aux(cfg, auxs)
     x = L.apply_norm(cfg, params["ln_f"], x)
     return L.unembed(cfg, params["embed"], x, padded=padded_logits), aux
 
 
-def loss_fn(cfg: ModelConfig, params, batch, rng=None, impl: str = "ref",
-            aux_weight: float = 0.01):
-    logits, aux = forward(cfg, params, batch, impl=impl, padded_logits=True)
+def loss_fn(cfg: ModelConfig, params, batch, rng=None, impl: str = "ref"):
+    """-> (next-token loss + ``cfg.moe_aux_coef`` x load-balancing loss,
+    the expert layers' stats: `moe.STATS` summed over layers in the
+    dropless mode, else empty)."""
+    logits, (aux, stats) = forward(cfg, params, batch, impl=impl,
+                                   padded_logits=True)
     loss = L.softmax_xent(logits[:, :-1], batch["tokens"][:, 1:],
                           batch.get("mask"), valid_vocab=cfg.vocab_size)
-    return loss + aux_weight * aux
+    return loss + cfg.moe_aux_coef * aux, stats
 
 
 # ------------------------------------------------------------- serving -----

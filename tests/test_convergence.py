@@ -36,7 +36,7 @@ def _loss_fn_for(A, b):
     def loss(params, batch, rng):
         i = batch["client"]
         r = A[i] @ params["w"] - b[i]
-        return 0.5 * jnp.sum(r * r)
+        return 0.5 * jnp.sum(r * r), {}
 
     return loss
 

@@ -140,7 +140,7 @@ def test_masked_mean_and_count():
 
 # ---------------------------------------------------------- micro batching --
 def test_micro_value_and_grad_rejects_indivisible_batch():
-    loss = lambda p, b, k: jnp.mean(p * b["x"])
+    loss = lambda p, b, k: (jnp.mean(p * b["x"]), {})
     vg = micro_value_and_grad(loss, num_micro=3)
     with pytest.raises(ValueError, match="not.*divisible by micro_batches=3"):
         jax.jit(vg)(jnp.ones(()), {"x": jnp.ones((4, 2))},
@@ -148,11 +148,11 @@ def test_micro_value_and_grad_rejects_indivisible_batch():
 
 
 def test_micro_value_and_grad_matches_full_batch_when_divisible():
-    loss = lambda p, b, k: jnp.mean((p - b["x"]) ** 2)
+    loss = lambda p, b, k: (jnp.mean((p - b["x"]) ** 2), {})
     p = jnp.float32(0.3)
     batch = {"x": jnp.arange(8, dtype=jnp.float32).reshape(8, 1)}
     key = jax.random.PRNGKey(0)
-    l1, g1 = micro_value_and_grad(loss, 1)(p, batch, key)
-    l4, g4 = micro_value_and_grad(loss, 4)(p, batch, key)
+    (l1, _), g1 = micro_value_and_grad(loss, 1)(p, batch, key)
+    (l4, _), g4 = micro_value_and_grad(loss, 4)(p, batch, key)
     np.testing.assert_allclose(float(l1), float(l4), rtol=1e-6)
     np.testing.assert_allclose(float(g1), float(g4), rtol=1e-6)

@@ -283,7 +283,7 @@ def _toy_sim(policy, n=4, rounds=10, energy=None, phase=None, seed=0):
 
     def loss(params, batch, rng):
         r = params["w"] - b[batch["client"]]
-        return 0.5 * jnp.sum(r * r)
+        return 0.5 * jnp.sum(r * r), {}
 
     def batch_fn(rnd, i):
         return {"client": jnp.full((2,), i, jnp.int32)}
@@ -571,7 +571,7 @@ def test_simulate_closed_loop_with_controller():
 
     def loss(params, batch, rng):
         r = params["w"] - b[batch["client"]]
-        return 0.5 * jnp.sum(r * r)
+        return 0.5 * jnp.sum(r * r), {}
 
     def batch_fn(rnd, i, num_steps):   # adaptive-T contract: (T, B) batches
         return {"client": jnp.full((num_steps, 2), i, jnp.int32)}

@@ -47,9 +47,10 @@ def test_smoke_forward_and_train_step(arch, rng):
 
     opt = adam(1e-3)
     state = opt.init(params)
-    loss0, grads = jax.value_and_grad(lambda p: model.loss_fn(p, batch))(params)
+    (loss0, _), grads = jax.value_and_grad(
+        lambda p: model.loss_fn(p, batch), has_aux=True)(params)
     params2, _ = opt.update(grads, state, params, 0)
-    loss1 = model.loss_fn(params2, batch)
+    loss1, _ = model.loss_fn(params2, batch)
     assert np.isfinite(float(loss0)) and np.isfinite(float(loss1))
     assert float(loss1) < float(loss0)  # one step on the same batch improves
 

@@ -16,7 +16,7 @@ from repro.optim import adam, sgd
 
 def _quad_loss(p, batch, rng):
     x, y = batch
-    return 0.5 * jnp.mean((x @ p["w"] + p["b"] - y) ** 2)
+    return 0.5 * jnp.mean((x @ p["w"] + p["b"] - y) ** 2), {}
 
 
 def _setup(C=6, T=3, B=4, d=3, seed=0):
@@ -50,7 +50,7 @@ def test_parallel_round_equals_manual():
             s = opt.init(params)
             for t in range(T):
                 bt = jax.tree.map(lambda b: b[t], cb)
-                g = jax.grad(lambda q: _quad_loss(q, bt, None))(params)
+                g = jax.grad(lambda q: _quad_loss(q, bt, None)[0])(params)
                 params, s = opt.update(g, s, params, jnp.int32(t))
             w_stack.append(params)
         w_stack = jax.tree.map(lambda *xs: jnp.stack(xs), *w_stack)
@@ -76,7 +76,7 @@ def test_sequential_equals_parallel():
     acc = zeros_like_fp32(w0)
     for i in range(C):
         cb = jax.tree.map(lambda b: b[i], batches)
-        acc, _ = sequential_client_step(
+        acc, _, _ = sequential_client_step(
             _quad_loss, opt, cfg, w0, acc, cb, p[i], E[i], mask[i],
             jax.random.fold_in(key, i))
     w_seq = finish_sequential_round(cfg, w0, acc)
@@ -124,7 +124,8 @@ def _noisy_loss(p, batch, rng):
     matches another only if each client step gets the same key."""
     x, y = batch
     noise = jax.random.normal(rng, y.shape)
-    return _quad_loss(p, batch, rng) + 0.1 * jnp.mean(noise * (x @ p["w"]))
+    return (_quad_loss(p, batch, rng)[0]
+            + 0.1 * jnp.mean(noise * (x @ p["w"])), {})
 
 
 OPTIMIZERS = {"sgd": lambda: sgd(0.1),

@@ -19,7 +19,7 @@ def _mlp_loss(params, batch, rng):
     logits = h @ params["w2"] + params["b2"]
     logz = jax.nn.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, batch["labels"][:, None], axis=-1)[:, 0]
-    return jnp.mean(logz - gold)
+    return jnp.mean(logz - gold), {}
 
 
 def _mlp_init(key, d_in=32 * 32 * 3, hidden=32, classes=10):
@@ -61,7 +61,8 @@ def _run_policy(policy, rounds=30, C=8, T=5, batch=16, seed=0, noise=0.35):
                    jax.random.PRNGKey(seed))
     acc = _accuracy(res.params, jnp.asarray(xte), jnp.asarray(yte))
     test_loss = float(_mlp_loss(res.params, {"images": jnp.asarray(xte),
-                                             "labels": jnp.asarray(yte)}, None))
+                                             "labels": jnp.asarray(yte)},
+                                 None)[0])
     return acc, res, test_loss
 
 

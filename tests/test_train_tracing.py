@@ -21,6 +21,7 @@ CHILDREN = ["train.batch", "train.args", "train.dispatch", "train.fetch",
             "train.after_round"]
 SCOPES = ["schedule", "broadcast", "local_step", "optimizer", "aggregate",
           "embed", "attention", "mlp", "head"]
+MOE_SCOPES = ["router", "dispatch", "experts", "combine"]
 
 
 def _run(arch="granite-3-2b"):
@@ -99,14 +100,18 @@ def test_obs_gets_span_events_and_counters_on_close(tmp_path):
     assert set(counters) >= set(run.counters)
 
 
-@pytest.mark.parametrize("arch", ["granite-3-2b", "whisper-tiny"])
+@pytest.mark.parametrize("arch", ["granite-3-2b", "whisper-tiny",
+                                  "olmoe-1b-7b"])
 def test_round_program_carries_every_scope(arch):
-    """Transformer and encoder-decoder: each scope names some op of the
-    lowered round, and in the compiled round the backward ops of each
-    model scope keep it (under ``transpose(jvp(...))``)."""
+    """Transformer, encoder-decoder and the dropless expert layer: each
+    scope names some op of the lowered round, and in the compiled round the
+    backward ops of each model scope keep it (under
+    ``transpose(jvp(...))``); the expert layer's own scopes sit inside
+    ``mlp``."""
     run = _run(arch)
     assert run.cfg.family == {"granite-3-2b": "dense",
-                              "whisper-tiny": "encdec"}[arch]
+                              "whisper-tiny": "encdec",
+                              "olmoe-1b-7b": "moe"}[arch]
     w = jax.eval_shape(run.init_params)
     args = (run.batch_fn(0), run.p, run.E, jnp.int32(0),
             jax.random.PRNGKey(0))
@@ -123,3 +128,43 @@ def test_round_program_carries_every_scope(arch):
     for scope in ("embed", "attention", "mlp", "head"):
         pat = re.compile(rf"[/(]{scope}([/)]|$)")
         assert any(pat.search(n) for n in backward), scope
+    if run.cfg.family == "moe":
+        for scope in MOE_SCOPES:
+            assert any(f"mlp/{scope}/" in n for n in compiled), scope
+
+
+@pytest.fixture(scope="module")
+def moe_rounds():
+    run = _run("olmoe-1b-7b")
+    w, hist = train_rounds(run, run.init_params(), 3)
+    return run, hist
+
+
+def test_moe_counters_are_consistent(moe_rounds):
+    """The dropless layer's counters over three rounds: the held
+    assignments are at most every assignment of every layer and
+    participant step, the busiest expert at least the mean one, and the
+    expert FLOPs 6 d ff a held assignment."""
+    run, hist = moe_rounds
+    cfg, c = run.cfg, {k: v.value for k, v in run.counters.items()}
+    steps = sum(int(h["participants"]) * T for h in hist)
+    tokens = 2 * 16                              # batch x seq a step
+    held = c["train.moe_assignments_held"]
+    assert 0 < held <= steps * tokens * cfg.experts_per_token * cfg.num_layers
+    assert c["train.moe_load_max"] >= c["train.moe_load_mean"] > 0
+    assert c["train.moe_load_mean"] == pytest.approx(held / cfg.num_experts)
+    assert c["train.moe_expert_flops"] == held * 6 * cfg.d_model * cfg.d_ff
+
+
+@pytest.mark.parametrize("arch", ["granite-3-2b", "whisper-tiny"])
+def test_rounds_without_expert_layer_count_no_moe_stats(arch):
+    """No counter and no round output beyond the loss, participants and
+    client steps."""
+    run = _run(arch)
+    assert run.stats == ()
+    assert not [n for n in run.counters if n.startswith("train.moe_")]
+    w = jax.eval_shape(run.init_params)
+    args = (run.batch_fn(0), run.p, run.E, jnp.int32(0),
+            jax.random.PRNGKey(0))
+    _, metrics = jax.eval_shape(run.round_fn, w, *args)
+    assert set(metrics) == {"loss", "participants", "client_steps"}
